@@ -187,7 +187,8 @@ class DCS3GD:
             wire0 = self._plan(wp).pack(wp) if self._reduces_weights \
                 else comm["delta_prev"]
             pl_state, rs = PL.issue(self.reducer, wire0,
-                                    comm.get("reducer"))
+                                    comm.get("reducer"),
+                                    fence=self._reduces_weights)
             comm["pipeline"] = pl_state
             if rs is not None:
                 comm["reducer"] = rs

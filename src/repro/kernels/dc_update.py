@@ -13,9 +13,9 @@ plus the two norm reductions of Eq. 17 fused into a single read of (g, D).
 TPU adaptation: blocks are (ROWS, 128) f32 tiles in VMEM (lane dim 128,
 sublane multiple of 8); tensors are flattened and padded to tile boundaries
 by the ops.py wrapper.  Grid iterations on TPU execute sequentially per
-core, so the norm kernel accumulates its two partial sums into a (1, 1)
-output block mapped to every grid step (init on step 0) — the standard
-Pallas reduction idiom.
+core, so the norm kernel accumulates its two partial sums into (1, 1)
+outputs held in SMEM for the whole grid (init on step 0) — the standard
+Pallas reduction idiom; Mosaic stores scalars to SMEM only, never VMEM.
 """
 from __future__ import annotations
 
@@ -24,6 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 ROWS = 256          # sublane rows per block (multiple of 8)
 LANES = 128         # TPU lane width
@@ -55,6 +56,7 @@ def dc_norms(g2d: jnp.ndarray, d2d: jnp.ndarray, *, interpret: bool = False):
     padding contributes nothing to either sum).  Returns (gsq, csq) scalars."""
     m = g2d.shape[0]
     grid = (m // ROWS,)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     gsq, csq = pl.pallas_call(
         _dc_norms_kernel,
         grid=grid,
@@ -62,10 +64,7 @@ def dc_norms(g2d: jnp.ndarray, d2d: jnp.ndarray, *, interpret: bool = False):
             pl.BlockSpec((ROWS, LANES), lambda i: (i, 0)),
             pl.BlockSpec((ROWS, LANES), lambda i: (i, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
+        out_specs=[smem, smem],
         out_shape=[
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),

@@ -32,6 +32,7 @@ calls.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from functools import partial
 from typing import Any, Callable, Optional, Tuple
@@ -58,15 +59,11 @@ CKPT_ALGO_KEYS = ("algo", "reducer", "reducer_opts", "local_optimizer",
 
 
 def mesh_context(mesh):
-    """Context manager activating a mesh (jax >= 0.5 spells it
-    jax.sharding.set_mesh; older releases use the Mesh itself); a no-op
-    context when ``mesh`` is None."""
+    """Context manager activating a mesh (`jax.sharding.set_mesh`); a
+    no-op context when ``mesh`` is None."""
     if mesh is None:
-        import contextlib
         return contextlib.nullcontext()
-    if hasattr(jax.sharding, "set_mesh"):
-        return jax.sharding.set_mesh(mesh)
-    return mesh
+    return jax.sharding.set_mesh(mesh)
 
 
 class Engine:
@@ -151,7 +148,17 @@ class Engine:
     # -- training -----------------------------------------------------------
 
     def init_state(self, key) -> PyTree:
-        return self.alg.init(self.model.init(key))
+        """The initial `TrainState`.  With a mesh, ``init`` is jitted with
+        the state shardings as its outputs, so every worker's replica is
+        created on its own devices (none passes through device 0)."""
+        def init(k):
+            return self.alg.init(self.model.init(k))
+        if self.mesh is None:
+            return init(key)
+        abstract = jax.eval_shape(init, key)
+        st_sh = self._shard(self.alg.state_specs(self.model.cfg, abstract,
+                                                 self.mesh_axes()))
+        return jax.jit(init, out_shardings=st_sh)(key)
 
     def jit_train_step(self, state: Optional[PyTree] = None,
                        batch: Optional[PyTree] = None, *,
@@ -345,7 +352,7 @@ class Engine:
                 m = {k: float(v)
                      for k, v in jax.device_get(metrics).items()}
                 m["step"] = it
-                m["wall_s"] = round(time.time() - t0, 1)
+                m["wall_s"] = time.time() - t0
                 if measuring:
                     m["measured_skew"] = max(progress) - min(progress)
                 if elastic:

@@ -42,6 +42,7 @@ import numpy as np
 from repro.checkpoint import restore_pytree
 from repro.configs import ARCHS, get_config, reduced
 from repro.core import registry
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.engine import SAMPLERS, Engine, algorithm_for_checkpoint
 from repro.models.transformer import Model
 from repro.serve import Request, Scheduler
@@ -233,6 +234,7 @@ def main(argv=None):
                     choices=registry.names(registry.REDUCER),
                     help="fallback for pre-metadata checkpoints")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

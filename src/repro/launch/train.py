@@ -31,11 +31,14 @@ from pathlib import Path
 import jax
 
 from repro.checkpoint import checkpoint_exists, checkpoint_meta
+from repro.cluster.spec import ClusterSpec
 from repro.configs import ARCHS, get_config, reduced
 from repro.core import registry
 from repro.core.types import DCS3GDConfig
 from repro.data import SyntheticLMDataset, worker_batches
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.engine import CKPT_ALGO_KEYS, Engine
+from repro.launch.mesh import mesh_for_spec
 from repro.models.transformer import Model
 
 
@@ -161,11 +164,21 @@ def _adopt_resume_meta(args) -> None:
     print(f"[train] resume metadata: {adopted}")
 
 
+def _worker_mesh(n_workers: int, elastic: bool):
+    """One DC-S3GD worker per device: a ``(data, model=1)`` mesh with
+    ``data = gcd(W, devices)`` when more than one device is visible.  One
+    device, or an elastic run (whose worker count changes under a fixed
+    mesh), keeps the unsharded path."""
+    if jax.device_count() == 1 or elastic:
+        return None
+    return mesh_for_spec(ClusterSpec.uniform(n_workers))
+
+
 def run(args) -> dict:
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
-    model = Model(cfg, remat=False, moe_dense=args.reduced,
+    model = Model(cfg, remat=True, moe_dense=args.reduced,
                   q_chunk=64, kv_chunk=64, scan_chunk=64, loss_chunk=256)
 
     # an explicit --workers on resume is an elastic-resume request: the
@@ -210,8 +223,8 @@ def run(args) -> dict:
               f"plan_block={plan_block}")
 
     key = jax.random.PRNGKey(args.seed)
-    params = model.init(key)
-    n_params = sum(x.size for x in jax.tree.leaves(params))
+    n_params = sum(x.size for x in jax.tree.leaves(
+        jax.eval_shape(model.init, key)))
     reducer = registry.make_reducer(args.reducer, dc_cfg,
                                     **(getattr(args, "reducer_opts", None)
                                        or {}))
@@ -219,8 +232,10 @@ def run(args) -> dict:
                         reducer=reducer, staleness=args.staleness,
                         use_kernels=args.use_kernels, buckets=args.buckets,
                         overlap=args.overlap, plan_block=plan_block)
-    engine = Engine(model, alg)
-    state = alg.init(params)
+    elastic = (resize_to is not None or args.fault_schedule is not None
+               or args.eject_skew is not None)
+    engine = Engine(model, alg, mesh=_worker_mesh(args.workers, elastic))
+    state = engine.init_state(key)
 
     data = SyntheticLMDataset(cfg.vocab_size, args.seq, seed=args.seed)
 
@@ -278,6 +293,10 @@ def run(args) -> dict:
     result = {
         "arch": cfg.name, "algo": args.algo, "steps": args.steps,
         "workers": final_workers, "final_loss": history[-1]["loss"],
+        # placement: the fewest devices any TrainState leaf spans (W on a
+        # one-worker-per-device mesh, 1 on the unsharded path)
+        "state_devices": min(len(x.sharding.device_set)
+                             for x in jax.tree.leaves(state)),
         "wall_s": round(wall, 1),
         "tokens_per_s": round(args.steps * args.workers
                               * args.batch_per_worker * args.seq / wall, 1),
@@ -297,6 +316,7 @@ def run(args) -> dict:
 
 
 def main(argv=None):
+    use_compile_cache()
     run(build_argparser().parse_args(argv))
 
 

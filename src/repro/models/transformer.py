@@ -458,6 +458,20 @@ class Model:
 
     # -------------------------------------------------- shared pieces
 
+    def _cast(self, params) -> PyTree:
+        """The floating parameters at ``compute_dtype``: each entry point
+        runs on this one cast copy, so the f32 master weights (and the
+        optimizer state built on them) keep their dtype while every
+        matmul and scan carry stays at the compute dtype.  A no-op when
+        the two dtypes agree."""
+        dt = self.compute_dtype
+
+        def cast(p):
+            if jnp.issubdtype(p.dtype, jnp.floating) and p.dtype != dt:
+                return p.astype(dt)
+            return p
+        return jax.tree.map(cast, params)
+
     def _ctx(self, S, extra=None):
         ctx = {
             "q_chunk": min(self.q_chunk, S),
@@ -536,6 +550,7 @@ class Model:
 
     def loss(self, params, batch) -> jnp.ndarray:
         cfg = self.cfg
+        params = self._cast(params)
         x = self._embed(params, batch)
         S = x.shape[1]
         extra = {"positions": jnp.arange(S)}
@@ -557,6 +572,7 @@ class Model:
     def logits(self, params, batch) -> jnp.ndarray:
         """Full-sequence logits (small-scale use: smoke tests, examples)."""
         cfg = self.cfg
+        params = self._cast(params)
         x = self._embed(params, batch)
         S = x.shape[1]
         extra = {"positions": jnp.arange(S)}
@@ -592,6 +608,7 @@ class Model:
     def prefill(self, params, batch, cache_len: int) -> Tuple[jnp.ndarray, PyTree]:
         """Forward over the prompt; returns (last-token logits, cache)."""
         cfg = self.cfg
+        params = self._cast(params)
         x = self._embed(params, batch)
         S = x.shape[1]
         extra = {"positions": jnp.arange(S), "cache_len": cache_len}
@@ -615,6 +632,7 @@ class Model:
         and the updated caches.  Only attention / MLA kinds: the layout
         gates chunkability before dispatch."""
         cfg = self.cfg
+        params = self._cast(params)
         x = jnp.take(params["embed"]["tok"], batch["tokens"], axis=0)
         x = x.astype(self.compute_dtype)
         if cfg.rope_theta == 0.0:  # absolute positions (mid-prompt offset)
@@ -685,6 +703,7 @@ class Model:
         layout, ``batch['pos']`` may be a per-row (B,) vector (continuous
         batching: every slot at its own position)."""
         cfg = self.cfg
+        params = self._cast(params)
         x = jnp.take(params["embed"]["tok"], batch["tokens"], axis=0)
         x = x.astype(self.compute_dtype)
         if cfg.rope_theta == 0.0:  # absolute positions (whisper decoder)

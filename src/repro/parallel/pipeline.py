@@ -104,8 +104,8 @@ def validate(*, buckets: int, reducer, staleness=None) -> None:
             "with the compressed reducer")
 
 
-def issue(reducer, wire: List, rstate: Optional[PyTree] = None
-          ) -> Tuple[dict, Optional[PyTree]]:
+def issue(reducer, wire: List, rstate: Optional[PyTree] = None, *,
+          fence: bool = True) -> Tuple[dict, Optional[PyTree]]:
     """Put the next payload on the wire: apply the reducer to the bucket
     list NOW (at the tail of the current step's program) and carry the
     result as the in-flight pipeline state.
@@ -120,8 +120,13 @@ def issue(reducer, wire: List, rstate: Optional[PyTree] = None
     issue into the tail arithmetic that produced the payload (FMA /
     reassociation across the seam), breaking the bitwise-equal-to-inline
     guarantee for reducers whose last ops are multiplies (gossip's
-    weighted neighbor sums)."""
-    wire = jax.lax.optimization_barrier(wire)
+    weighted neighbor sums).  ``fence=False`` is for a constant payload
+    (``init()``'s zeros), which no arithmetic produced: fenced, it would
+    be materialised, and on a worker mesh the partitioner lays such a
+    constant out whole on every device (W× the bucket bytes of
+    temporaries)."""
+    if fence:
+        wire = jax.lax.optimization_barrier(wire)
     # the `wire` scope tags the reducer body's HLO locations so
     # repro.analysis.lint can attribute comm_dtype casts to the simulated
     # wire (dtype-drift / wire-accounting passes) — same scope the inline

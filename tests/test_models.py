@@ -177,3 +177,26 @@ def test_vocab_padding_masks_pad_logits():
     toks = random.randint(random.PRNGKey(1), (1, 4), 0, 500)
     lg = m.logits(params, {"tokens": toks})
     assert bool((lg[..., 500:] < -1e29).all())
+
+
+@pytest.mark.parametrize("entry", ["loss", "prefill"])
+def test_published_config_traces_in_mixed_precision(entry):
+    """The published qwen3-0.6b keeps f32 parameters and computes in
+    bf16.  Each entry point casts the parameters to the compute dtype
+    once, so the per-layer scan carry keeps one dtype and the full-width
+    program traces (shapes only: nothing is allocated)."""
+    cfg = get_config("qwen3-0.6b")
+    m = _model(cfg, remat=True, q_chunk=64, kv_chunk=64, scan_chunk=64,
+               loss_chunk=256)
+    params = jax.eval_shape(m.init, random.PRNGKey(0))
+    assert {x.dtype for x in jax.tree.leaves(params)} == {jnp.dtype("float32")}
+    assert m.compute_dtype == jnp.bfloat16
+    tokens = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    if entry == "loss":
+        out = jax.eval_shape(m.loss, params,
+                             {"tokens": tokens, "labels": tokens})
+        assert out.shape == ()
+    else:
+        out, _ = jax.eval_shape(lambda p, b: m.prefill(p, b, cache_len=256),
+                                params, {"tokens": tokens})
+        assert out.shape == (2, m.vocab_padded)

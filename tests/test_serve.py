@@ -366,11 +366,15 @@ def test_scheduler_eos_evicts_early():
     sch = Scheduler(m, params, slots=1, pages=12, page_size=8, max_len=48)
     [probe] = sch.run([Request(rid=0, prompt=prompt, max_new=12)])
     assert len(probe.out) == 12
-    eos = probe.out[4]
+    # EOS = the first decoded token not seen earlier in the output, so its
+    # first occurrence is where the probe's output must be cut
+    cut = next(i for i in range(1, len(probe.out) - 1)
+               if probe.out[i] not in probe.out[:i])
+    eos = probe.out[cut]
     sch2 = Scheduler(m, params, slots=1, pages=12, page_size=8, max_len=48,
                      eos_id=eos)
     [early] = sch2.run([Request(rid=0, prompt=prompt, max_new=12)])
-    assert early.out == probe.out[:5], "evict ON the eos token"
+    assert early.out == probe.out[:cut + 1], "evict ON the eos token"
     assert sch2.pool.used_pages == 0
 
 

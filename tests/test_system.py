@@ -46,6 +46,32 @@ def test_train_checkpoint_resume(tmp_path):
     assert ck.with_suffix(".npz").exists() or ck.exists()
 
 
+@pytest.mark.parametrize("env_set", [True, False], ids=["env", "checkout"])
+def test_compile_cache_dir_rule(monkeypatch, tmp_path, env_set):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX (nothing is set
+    in code); unset, the cache lives at a fixed, git-ignored directory
+    of the checkout."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache as CC
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if env_set:
+            monkeypatch.setenv(CC.ENV_VAR, str(tmp_path))
+            assert CC.use_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv(CC.ENV_VAR, raising=False)
+            root = Path(__file__).resolve().parents[1]
+            assert CC.use_compile_cache() == str(root / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == str(
+                root / ".jax_cache")
+            assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 def test_serve_generate_greedy_deterministic():
     cfg = reduced(get_config("qwen3-0.6b"))
     m = Model(cfg, remat=False, q_chunk=16, kv_chunk=16, scan_chunk=16)
