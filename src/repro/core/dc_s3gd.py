@@ -281,106 +281,111 @@ class DCS3GD:
         # --- g_i = ∇l(w_i): per-worker gradients (the compute overlapped)
         grads, loss = _vgrads(loss_fn, state.params, batch, cfg.microbatches)
 
-        # --- MPI_Wait() / D_i = (1/N)·Δ̄w − Δw_i  (Eq. 9); for weight
-        # reducers D_i = R(w)_i − w_i directly (same quantity: distance
-        # from my weights to my reduction target).  With buckets, D stays
-        # in the flat-buffer representation until a consumer needs leaves.
-        if self._reduces_weights:
-            D = jax.tree.map(lambda rw, w: rw - w.astype(jnp.float32),
-                             w_red, wire)
-        else:
-            D = jax.tree.map(lambda db, d: db - d.astype(jnp.float32),
-                             delta_bar, delta_prev)
-        # fence D as well: downstream reductions (the compensator's Eq. 17
-        # norms) must see a materialized buffer so their codegen cannot
-        # depend on which program region produced the reduction
-        D = jax.lax.optimization_barrier(D)
+        # `tail`: everything after the gradients (D, the compensator, the
+        # local optimizer, Eq. 12, the next wire state), one name for
+        # bench/scopes.py to charge the update's device time to
+        with jax.named_scope("tail"):
+            # --- MPI_Wait() / D_i = (1/N)·Δ̄w − Δw_i  (Eq. 9); for weight
+            # reducers D_i = R(w)_i − w_i directly (same quantity: distance
+            # from my weights to my reduction target).  With buckets, D stays
+            # in the flat-buffer representation until a consumer needs leaves.
+            if self._reduces_weights:
+                D = jax.tree.map(lambda rw, w: rw - w.astype(jnp.float32),
+                                 w_red, wire)
+            else:
+                D = jax.tree.map(lambda db, d: db - d.astype(jnp.float32),
+                                 delta_bar, delta_prev)
+            # fence D as well: downstream reductions (the compensator's Eq. 17
+            # norms) must see a materialized buffer so their codegen cannot
+            # depend on which program region produced the reduction
+            D = jax.lax.optimization_barrier(D)
 
-        # --- staleness policy: may this step use the stale overlapped
-        # window?  'fixed' is stateless and skips the branch (bitwise the
-        # paper behaviour); 'dynamic_ssp' revokes the window when the
-        # observed per-worker step skew exceeds its threshold, falling
-        # back to a blocking pull toward the current weight average.
-        pstate = None
-        pol_metrics = {}
-        if not self.staleness.stateless:
-            admit, pstate = self.staleness.admit(state.comm["staleness"])
+            # --- staleness policy: may this step use the stale overlapped
+            # window?  'fixed' is stateless and skips the branch (bitwise the
+            # paper behaviour); 'dynamic_ssp' revokes the window when the
+            # observed per-worker step skew exceeds its threshold, falling
+            # back to a blocking pull toward the current weight average.
+            pstate = None
+            pol_metrics = {}
+            if not self.staleness.stateless:
+                admit, pstate = self.staleness.admit(state.comm["staleness"])
 
-            def _sync_pull():
-                wbar = jax.tree.map(
-                    lambda p: jnp.mean(p.astype(jnp.float32), axis=0,
-                                       keepdims=True), state.params)
-                Dt = jax.tree.map(
-                    lambda wb, w: wb - w.astype(jnp.float32),
-                    wbar, state.params)
-                # match the admitted branch's representation
-                return plan.pack(Dt) if plan is not None else Dt
+                def _sync_pull():
+                    wbar = jax.tree.map(
+                        lambda p: jnp.mean(p.astype(jnp.float32), axis=0,
+                                           keepdims=True), state.params)
+                    Dt = jax.tree.map(
+                        lambda wb, w: wb - w.astype(jnp.float32),
+                        wbar, state.params)
+                    # match the admitted branch's representation
+                    return plan.pack(Dt) if plan is not None else Dt
 
-            # lax.cond (not where): the revoked-window branch costs a full
-            # params-tree mean — only pay it on the steps that take it
-            D = jax.lax.cond(admit, lambda: D, _sync_pull)
-            if rstate is not None and hasattr(self.reducer, "revoke"):
-                # a revoked window discards the reducer output: the
-                # compressed payload never reached the trajectory, so it
-                # must return to the error-feedback residual, not vanish
-                rstate = jax.lax.cond(
-                    admit, lambda: rstate,
-                    lambda: self.reducer.revoke(
-                        r_in, state.comm["reducer"], rstate))
-            pol_metrics = {"ssp_admit": admit.astype(jnp.float32)}
+                # lax.cond (not where): the revoked-window branch costs a full
+                # params-tree mean — only pay it on the steps that take it
+                D = jax.lax.cond(admit, lambda: D, _sync_pull)
+                if rstate is not None and hasattr(self.reducer, "revoke"):
+                    # a revoked window discards the reducer output: the
+                    # compressed payload never reached the trajectory, so it
+                    # must return to the error-feedback residual, not vanish
+                    rstate = jax.lax.cond(
+                        admit, lambda: rstate,
+                        lambda: self.reducer.revoke(
+                            r_in, state.comm["reducer"], rstate))
+                pol_metrics = {"ssp_admit": admit.astype(jnp.float32)}
 
-        if self.use_kernels:
-            return self._fused_tail(state, grads, D, loss, lr, wd,
-                                    plan=plan, pstate=pstate,
-                                    pol_metrics=pol_metrics, rstate=rstate)
+            if self.use_kernels:
+                return self._fused_tail(state, grads, D, loss, lr, wd,
+                                        plan=plan, pstate=pstate,
+                                        pol_metrics=pol_metrics, rstate=rstate)
 
-        if plan is not None:
-            # per-leaf reference tail: leave the flat-buffer world here.
-            # The unpack is a static reshape/slice, so the bucketed wire is
-            # bitwise the per-leaf wire for mean-style reducers.
-            D = plan.unpack(D)
+            if plan is not None:
+                # per-leaf reference tail: leave the flat-buffer world here.
+                # The unpack is a static reshape/slice, so the bucketed wire is
+                # bitwise the per-leaf wire for mean-style reducers.
+                D = plan.unpack(D)
 
-        # --- g̃_i = g_i + λ_i g_i⊙g_i⊙D_i  (Eq. 10 + 17)
-        g_t, lam = self.compensator(grads, D, axis0_is_worker=True)
+            # --- g̃_i = g_i + λ_i g_i⊙g_i⊙D_i  (Eq. 10 + 17)
+            g_t, lam = self.compensator(grads, D, axis0_is_worker=True)
 
-        # --- Δw_i = U(g̃_i, η, μ)  (Eq. 11).  axis0_is_worker: the decay
-        # mask must judge canonical rank, not (W, ...)-stacked rank —
-        # otherwise norm/bias vectors get decayed (and the fused tail,
-        # which sees canonical leaves under vmap, would disagree).
-        delta, opt = self.local_optimizer(g_t, state.opt, state.params,
-                                          sched, axis0_is_worker=True)
+            # --- Δw_i = U(g̃_i, η, μ)  (Eq. 11).  axis0_is_worker: the decay
+            # mask must judge canonical rank, not (W, ...)-stacked rank —
+            # otherwise norm/bias vectors get decayed (and the fused tail,
+            # which sees canonical leaves under vmap, would disagree).
+            delta, opt = self.local_optimizer(g_t, state.opt, state.params,
+                                              sched, axis0_is_worker=True)
 
-        # --- w_i = w_i + D_i + Δw_i  (Eq. 12: move toward the average +
-        # corrected update in one pass)
-        new_params = jax.tree.map(
-            lambda w, d_i, dw: (w.astype(jnp.float32) + d_i
-                                + dw.astype(jnp.float32)).astype(w.dtype),
-            state.params, D, delta)
+            # --- w_i = w_i + D_i + Δw_i  (Eq. 12: move toward the average +
+            # corrected update in one pass)
+            new_params = jax.tree.map(
+                lambda w, d_i, dw: (w.astype(jnp.float32) + d_i
+                                    + dw.astype(jnp.float32)).astype(w.dtype),
+                state.params, D, delta)
 
-        sdt = jnp.dtype(cfg.state_dtype)
-        opt = jax.tree.map(lambda x: x.astype(sdt) if x.ndim else x, opt)
-        metrics = {
-            "loss": jnp.mean(loss),
-            "lr": lr,
-            "wd": wd,
-            "lambda": jnp.mean(lam) if not isinstance(lam, dict) else
-            jnp.mean(jnp.stack([jnp.mean(v) for v in jax.tree.leaves(lam)])),
-            "distance_norm": _mean_worker_norm(D),
-            "delta_norm": _mean_worker_norm(delta),
-            **pol_metrics,
-        }
-        next_wire = None
-        if self.overlap and self._reduces_weights:
-            # fence BEFORE packing: the issue must not add a fusion
-            # consumer to the weight-update expression, or the stored
-            # params themselves shift by an ulp vs the inline program
-            new_params = jax.lax.optimization_barrier(new_params)
-            next_wire = plan.pack(new_params)
-        return TrainState(new_params, opt,
-                          self._comm(delta, sdt, pstate, plan=plan,
-                                     rstate=rstate, prev_comm=state.comm,
-                                     next_wire=next_wire),
-                          state.step + 1), metrics
+            sdt = jnp.dtype(cfg.state_dtype)
+            opt = jax.tree.map(lambda x: x.astype(sdt) if x.ndim else x, opt)
+            metrics = {
+                "loss": jnp.mean(loss),
+                "lr": lr,
+                "wd": wd,
+                "lambda": jnp.mean(lam) if not isinstance(lam, dict) else
+                jnp.mean(jnp.stack([jnp.mean(v)
+                                    for v in jax.tree.leaves(lam)])),
+                "distance_norm": _mean_worker_norm(D),
+                "delta_norm": _mean_worker_norm(delta),
+                **pol_metrics,
+            }
+            next_wire = None
+            if self.overlap and self._reduces_weights:
+                # fence BEFORE packing: the issue must not add a fusion
+                # consumer to the weight-update expression, or the stored
+                # params themselves shift by an ulp vs the inline program
+                new_params = jax.lax.optimization_barrier(new_params)
+                next_wire = plan.pack(new_params)
+            return TrainState(new_params, opt,
+                              self._comm(delta, sdt, pstate, plan=plan,
+                                         rstate=rstate, prev_comm=state.comm,
+                                         next_wire=next_wire),
+                              state.step + 1), metrics
 
     def _comm(self, delta: PyTree, sdt, pstate: Optional[PyTree] = None, *,
               plan=None, packed: bool = False,

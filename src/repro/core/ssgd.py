@@ -128,12 +128,14 @@ class SSGD:
             with jax.named_scope("wire"):
                 red = self.reducer(g32)
             grads = collapse_worker_axis(red)
-        delta, opt = self.local_optimizer(grads, state.opt, state.params,
-                                          {"lr": lr, "weight_decay": wd})
-        new_params = jax.tree.map(
-            lambda w, dw: (w.astype(jnp.float32)
-                           + dw.astype(jnp.float32)).astype(w.dtype),
-            state.params, delta)
+        # `tail`: the update, named as DC-S3GD's (bench/scopes.py)
+        with jax.named_scope("tail"):
+            delta, opt = self.local_optimizer(grads, state.opt, state.params,
+                                              {"lr": lr, "weight_decay": wd})
+            new_params = jax.tree.map(
+                lambda w, dw: (w.astype(jnp.float32)
+                               + dw.astype(jnp.float32)).astype(w.dtype),
+                state.params, delta)
         return (TrainState(new_params, opt, comm, state.step + 1),
                 {"loss": jnp.mean(loss), "lr": lr, "wd": wd})
 

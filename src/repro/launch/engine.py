@@ -214,7 +214,8 @@ class Engine:
             steps: int, start: int = 0, log_every: int = 10,
             verbose: bool = True, measure_skew: bool = False,
             skew_probe: Optional[Callable[[int, float], Any]] = None,
-            skew_warmup: int = 1, membership=None
+            skew_warmup: int = 1, membership=None,
+            profile_dir=None, profile_steps: int = 0
             ) -> Tuple[PyTree, list, float]:
         """Run the step loop; returns (state, metric history, wall s).
 
@@ -261,7 +262,13 @@ class Engine:
         ``batch_fn(it, n_workers)`` — the batch must follow the live
         worker count — and feed measured per-worker progress to the
         controller's ejection policy (under ``measure_skew``, which
-        works here even for the stateless ``fixed`` staleness policy)."""
+        works here even for the stateless ``fixed`` staleness policy).
+
+        Every step runs under ``StepTraceAnnotation("train")``, its batch
+        making under ``repro.batch`` and the logging fetch under
+        ``repro.fetch``: host spans on the profiler's clock.  With
+        ``profile_dir`` the profiler records ``profile_steps`` steps
+        after the first logged one (train ``--profile-dir``)."""
         elastic = membership is not None
         if elastic:
             self.alg = membership.alg
@@ -276,7 +283,8 @@ class Engine:
                     and not getattr(getattr(self.alg, "staleness", None),
                                     "stateless", True))
 
-        batch = make_batch(start) if steps > start else None
+        with jax.profiler.TraceAnnotation("repro.batch"):
+            batch = make_batch(start) if steps > start else None
         step_fn = self.jit_train_step(state, batch)
         self._fit_step_fn, self._fit_rejits = step_fn, 0
         stateful = stateful_policy()
@@ -286,85 +294,99 @@ class Engine:
         warmup = max(int(skew_warmup), 0)
         warm_until = start + warmup    # steps below this: compile spike
         history = []
+        trace_stop = None   # the step after which the profiler stops
         t0 = time.time()
         for it in range(start, steps):
-            rejit = False
-            if elastic:
-                events = membership.poll(it)
-                if events:
-                    state, rejit = membership.apply(events, state, step=it)
-                    if rejit:
-                        self.alg = membership.alg
-                        cur_w = membership.n_workers
-                        stateful = stateful_policy()
-                        n_workers = cur_w if measuring else 0
-                        # the transition is a barrier: everyone leaves it
-                        # in lockstep at the leader's virtual clock
-                        vprogress = [max(vprogress, default=0.0)] \
-                            * n_workers
-                        # re-jit => a fresh compile spike on the next
-                        # step: exclude it like the step-0 one
-                        warm_until = it + warmup
-            if it != start or rejit:
-                batch = make_batch(it)
-            if rejit:
-                step_fn = self.jit_train_step(state, batch)
-                self._fit_step_fn = step_fn
-                self._fit_rejits += 1
-            ts = time.perf_counter()
-            state, metrics = step_fn(state, batch)
-            if measuring:
-                # ONE host round-trip per measured step: when the policy
-                # is stateful the admit flag must come to the host anyway,
-                # so that device_get IS the timing sync — a separate
-                # block_until_ready before it would pay a second
-                # dispatch-queue drain for nothing (the fit metric fetch
-                # the lint host-sync audit flagged)
-                if stateful:
-                    admit = float(jax.device_get(
-                        metrics.get("ssp_admit", 1.0)))
-                else:
-                    jax.block_until_ready(metrics)
-                    admit = 1.0
-                dt = time.perf_counter() - ts
-                if it >= warm_until:
-                    durs = list(skew_probe(it, dt)) \
-                        if skew_probe is not None else [dt] * n_workers
-                    assert len(durs) == n_workers, (len(durs), n_workers)
-                    slow = membership.slowdown_factors(it) if elastic \
-                        else None
-                    if slow is not None:
-                        durs = [d * f for d, f in zip(durs, slow)]
-                    if stateful and admit == 0.0:
-                        # the policy revoked the window and did its
-                        # blocking pull: the sync resolved the skew, so
-                        # the measured counters collapse to the leader
-                        vprogress = [max(vprogress)] * n_workers
-                    wall = max(durs)
-                    vprogress = [p + (wall / d if d > 0 else 0.0)
-                                 for p, d in zip(vprogress, durs)]
-                progress = [int(p) for p in vprogress]
-                if stateful:
-                    state = self.alg.observe_progress(state, progress)
+            if profile_dir is not None and trace_stop is None and history:
+                # the first logged step has synced: trace from here
+                jax.profiler.start_trace(str(profile_dir))
+                trace_stop = it + max(profile_steps, 1) - 1
+            with jax.profiler.StepTraceAnnotation("train", step_num=it):
+                rejit = False
                 if elastic:
-                    membership.observe_progress(it, vprogress)
-            if it % log_every == 0 or it == steps - 1:
-                m = {k: float(v)
-                     for k, v in jax.device_get(metrics).items()}
-                m["step"] = it
-                m["wall_s"] = time.time() - t0
+                    events = membership.poll(it)
+                    if events:
+                        state, rejit = membership.apply(events, state, step=it)
+                        if rejit:
+                            self.alg = membership.alg
+                            cur_w = membership.n_workers
+                            stateful = stateful_policy()
+                            n_workers = cur_w if measuring else 0
+                            # the transition is a barrier: everyone leaves it
+                            # in lockstep at the leader's virtual clock
+                            vprogress = [max(vprogress, default=0.0)] \
+                                * n_workers
+                            # re-jit => a fresh compile spike on the next
+                            # step: exclude it like the step-0 one
+                            warm_until = it + warmup
+                if it != start or rejit:
+                    with jax.profiler.TraceAnnotation("repro.batch"):
+                        batch = make_batch(it)
+                if rejit:
+                    step_fn = self.jit_train_step(state, batch)
+                    self._fit_step_fn = step_fn
+                    self._fit_rejits += 1
+                ts = time.perf_counter()
+                state, metrics = step_fn(state, batch)
                 if measuring:
-                    m["measured_skew"] = max(progress) - min(progress)
-                if elastic:
-                    m["n_workers"] = cur_w
-                history.append(m)
-                if verbose:
-                    extra = ""
-                    if "distance_norm" in m:
-                        extra = (f" |D|={m['distance_norm']:.2e} "
-                                 f"lam={m.get('lambda', 0):.3f}")
-                    print(f"[train] step {it:5d} loss={m['loss']:.4f} "
-                          f"lr={m['lr']:.4f}{extra}")
+                    # ONE host round-trip per measured step: when the policy
+                    # is stateful the admit flag must come to the host anyway,
+                    # so that device_get IS the timing sync — a separate
+                    # block_until_ready before it would pay a second
+                    # dispatch-queue drain for nothing (the fit metric fetch
+                    # the lint host-sync audit flagged)
+                    if stateful:
+                        admit = float(jax.device_get(
+                            metrics.get("ssp_admit", 1.0)))
+                    else:
+                        jax.block_until_ready(metrics)
+                        admit = 1.0
+                    dt = time.perf_counter() - ts
+                    if it >= warm_until:
+                        durs = list(skew_probe(it, dt)) \
+                            if skew_probe is not None else [dt] * n_workers
+                        assert len(durs) == n_workers, (len(durs), n_workers)
+                        slow = membership.slowdown_factors(it) if elastic \
+                            else None
+                        if slow is not None:
+                            durs = [d * f for d, f in zip(durs, slow)]
+                        if stateful and admit == 0.0:
+                            # the policy revoked the window and did its
+                            # blocking pull: the sync resolved the skew, so
+                            # the measured counters collapse to the leader
+                            vprogress = [max(vprogress)] * n_workers
+                        wall = max(durs)
+                        vprogress = [p + (wall / d if d > 0 else 0.0)
+                                     for p, d in zip(vprogress, durs)]
+                    progress = [int(p) for p in vprogress]
+                    if stateful:
+                        state = self.alg.observe_progress(state, progress)
+                    if elastic:
+                        membership.observe_progress(it, vprogress)
+                if it % log_every == 0 or it == steps - 1:
+                    with jax.profiler.TraceAnnotation("repro.fetch"):
+                        m = {k: float(v)
+                             for k, v in jax.device_get(metrics).items()}
+                    m["step"] = it
+                    m["wall_s"] = time.time() - t0
+                    if measuring:
+                        m["measured_skew"] = max(progress) - min(progress)
+                    if elastic:
+                        m["n_workers"] = cur_w
+                    history.append(m)
+                    if verbose:
+                        extra = ""
+                        if "distance_norm" in m:
+                            extra = (f" |D|={m['distance_norm']:.2e} "
+                                     f"lam={m.get('lambda', 0):.3f}")
+                        print(f"[train] step {it:5d} loss={m['loss']:.4f} "
+                              f"lr={m['lr']:.4f}{extra}")
+            if it == trace_stop:
+                jax.block_until_ready(state)
+                jax.profiler.stop_trace()
+        if trace_stop is not None and trace_stop >= steps:
+            jax.block_until_ready(state)
+            jax.profiler.stop_trace()
         return state, history, time.time() - t0
 
     # -- checkpointing with metadata -----------------------------------------

@@ -139,6 +139,12 @@ def build_argparser():
                          "elastic join before re-enabling a compressed "
                          "(error-feedback) reducer — drains the joiner's "
                          "inherited residual in one step")
+    ap.add_argument("--profile-dir", type=Path, default=None,
+                    help="write a profiler trace of --profile-steps steps "
+                         "after the first logged step here (host spans "
+                         "train/repro.*, device ops named by the model's "
+                         "scopes, which bench/scopes.py reads)")
+    ap.add_argument("--profile-steps", type=int, default=3)
     return ap
 
 
@@ -279,7 +285,8 @@ def run(args) -> dict:
     state, history, wall = engine.fit(
         state, batch_fn, steps=args.steps, start=start,
         log_every=args.log_every, measure_skew=args.measure_skew,
-        skew_warmup=args.skew_warmup, membership=membership)
+        skew_warmup=args.skew_warmup, membership=membership,
+        profile_dir=args.profile_dir, profile_steps=args.profile_steps)
 
     final_workers = membership.n_workers if membership is not None \
         else args.workers

@@ -158,45 +158,58 @@ def _apply_block(kind: str, cfg: ModelConfig, p: dict, x, ctx: dict,
     if kind == "attention_local":
         window = cfg.rglru.attention_window
 
+    # the `attn` and `mlp` scopes name the ops of each half of a block
+    # (bench/scopes.py charges device time to them); the residual adds
+    # stay outside, with the layer scan's own ops under `layers`
     if kind in ("attention", "attention_local", "cross", "mla"):
-        h = _norm(cfg, p["ln1"], x)
-        if kind == "mla":
-            h = attn.mla_train(p["attn"], h, positions, mla_cfg=cfg.mla,
-                               rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps,
-                               q_chunk=ctx["q_chunk"], kv_chunk=ctx["kv_chunk"])
-            if collect_cache:
-                cache_entry = _mla_cache_from_seq(p, cfg, x, positions, ctx)
-        else:
-            h = attn.attention_train(
-                p["attn"], h, positions, rope_theta=cfg.rope_theta,
-                window=window, qk_norm=cfg.qk_norm, norm_eps=cfg.norm_eps,
-                mrope_positions=ctx.get("mrope_positions"),
-                mrope_sections=cfg.vlm.mrope_sections if cfg.vlm else None,
-                q_chunk=ctx["q_chunk"], kv_chunk=ctx["kv_chunk"])
-            if collect_cache:
-                cache_entry = _kv_cache_from_seq(p, cfg, _norm(cfg, p["ln1"], x),
-                                                 positions, window, ctx)
+        with jax.named_scope("attn"):
+            h = _norm(cfg, p["ln1"], x)
+            if kind == "mla":
+                h = attn.mla_train(p["attn"], h, positions, mla_cfg=cfg.mla,
+                                   rope_theta=cfg.rope_theta,
+                                   norm_eps=cfg.norm_eps,
+                                   q_chunk=ctx["q_chunk"],
+                                   kv_chunk=ctx["kv_chunk"])
+                if collect_cache:
+                    cache_entry = _mla_cache_from_seq(p, cfg, x, positions,
+                                                      ctx)
+            else:
+                h = attn.attention_train(
+                    p["attn"], h, positions, rope_theta=cfg.rope_theta,
+                    window=window, qk_norm=cfg.qk_norm, norm_eps=cfg.norm_eps,
+                    mrope_positions=ctx.get("mrope_positions"),
+                    mrope_sections=cfg.vlm.mrope_sections if cfg.vlm
+                    else None,
+                    q_chunk=ctx["q_chunk"], kv_chunk=ctx["kv_chunk"])
+                if collect_cache:
+                    cache_entry = _kv_cache_from_seq(
+                        p, cfg, _norm(cfg, p["ln1"], x), positions, window,
+                        ctx)
         x = x + h
         if kind == "cross":
-            h = _norm(cfg, p["ln_x"], x)
-            enc = ctx["encoder_out"]
-            xk = jnp.einsum("bsd,dhk->bshk", enc, p["xattn"]["wk"])
-            xv = jnp.einsum("bsd,dhk->bshk", enc, p["xattn"]["wv"])
-            h = attn.attention_train(p["xattn"], h, positions,
-                                     rope_theta=0.0, causal=False,
-                                     kv_override=(xk, xv),
-                                     q_chunk=ctx["q_chunk"],
-                                     kv_chunk=ctx["kv_chunk"])
+            with jax.named_scope("attn"):
+                h = _norm(cfg, p["ln_x"], x)
+                enc = ctx["encoder_out"]
+                xk = jnp.einsum("bsd,dhk->bshk", enc, p["xattn"]["wk"])
+                xv = jnp.einsum("bsd,dhk->bshk", enc, p["xattn"]["wv"])
+                h = attn.attention_train(p["xattn"], h, positions,
+                                         rope_theta=0.0, causal=False,
+                                         kv_override=(xk, xv),
+                                         q_chunk=ctx["q_chunk"],
+                                         kv_chunk=ctx["kv_chunk"])
             x = x + h
             if collect_cache:
                 cache_entry = dict(cache_entry or {}, xk=xk, xv=xv)
-        h = _norm(cfg, p["ln2"], x)
-        if "moe" in p:
-            h, aux = moe_mod.moe_ffn(p["moe"], h, cfg.moe, cfg.activation) \
-                if not ctx.get("moe_dense") else \
-                moe_mod.moe_ffn_dense(p["moe"], h, cfg.moe, cfg.activation)
-        else:
-            h = mlp(p["mlp"], h, cfg.activation)
+        with jax.named_scope("mlp"):
+            h = _norm(cfg, p["ln2"], x)
+            if "moe" in p:
+                h, aux = moe_mod.moe_ffn(p["moe"], h, cfg.moe,
+                                         cfg.activation) \
+                    if not ctx.get("moe_dense") else \
+                    moe_mod.moe_ffn_dense(p["moe"], h, cfg.moe,
+                                          cfg.activation)
+            else:
+                h = mlp(p["mlp"], h, cfg.activation)
         x = x + h
     elif kind == "mamba":
         h = _norm(cfg, p["ln"], x)
@@ -213,8 +226,9 @@ def _apply_block(kind: str, cfg: ModelConfig, p: dict, x, ctx: dict,
             h = rglru_mod.rglru_forward(p["rglru"], h, cfg.rglru,
                                         chunk=ctx["scan_chunk"])
         x = x + h
-        h = _norm(cfg, p["ln2"], x)
-        h = mlp(p["mlp"], h, cfg.activation)
+        with jax.named_scope("mlp"):
+            h = _norm(cfg, p["ln2"], x)
+            h = mlp(p["mlp"], h, cfg.activation)
         x = x + h
     else:
         raise ValueError(kind)
@@ -540,8 +554,11 @@ class Model:
                 return (h, aux_c), (entries if collect_cache else None)
 
             fn = jax.checkpoint(unit_body) if self.remat else unit_body
-            (x, aux_total), ys = jax.lax.scan(fn, (x, aux_total),
-                                              params[f"stage{si}"])
+            # `layers`: what the scan itself moves (the stacked weights'
+            # slices, the gradients' stacking, the carry, residual adds)
+            with jax.named_scope("layers"):
+                (x, aux_total), ys = jax.lax.scan(fn, (x, aux_total),
+                                                  params[f"stage{si}"])
             if collect_cache:
                 caches.append(ys)
         return x, aux_total, caches
@@ -551,7 +568,8 @@ class Model:
     def loss(self, params, batch) -> jnp.ndarray:
         cfg = self.cfg
         params = self._cast(params)
-        x = self._embed(params, batch)
+        with jax.named_scope("embed"):
+            x = self._embed(params, batch)
         S = x.shape[1]
         extra = {"positions": jnp.arange(S)}
         if cfg.vlm is not None and "mrope_positions" in batch:
@@ -560,13 +578,14 @@ class Model:
             extra["encoder_out"] = self._encoder_out(params, batch["frames"])
         ctx = self._ctx(S, extra)
         x, aux, _ = self._backbone(params, x, ctx, False)
-        x = _norm(cfg, params["final_norm"], x)
-        labels = batch["labels"]
-        if cfg.vlm is not None and "patches" in batch:
-            # patches carry no next-token loss
-            pads = jnp.full(batch["patches"].shape[:2], -1, labels.dtype)
-            labels = jnp.concatenate([pads, labels], axis=1)
-        ce = chunked_xent(x, params["unembed"], labels, self.loss_chunk)
+        with jax.named_scope("head"):
+            x = _norm(cfg, params["final_norm"], x)
+            labels = batch["labels"]
+            if cfg.vlm is not None and "patches" in batch:
+                # patches carry no next-token loss
+                pads = jnp.full(batch["patches"].shape[:2], -1, labels.dtype)
+                labels = jnp.concatenate([pads, labels], axis=1)
+            ce = chunked_xent(x, params["unembed"], labels, self.loss_chunk)
         return ce + aux.astype(ce.dtype)
 
     def logits(self, params, batch) -> jnp.ndarray:
