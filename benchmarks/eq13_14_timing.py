@@ -48,8 +48,7 @@ def analytic_from_dryrun():
 
 def measured_cpu(algos=("dc_s3gd", "ssgd"), reducer: str = "mean_allreduce"):
     cfg = reduced(get_config("qwen3-0.6b"))
-    model = Model(cfg, remat=False, q_chunk=32, kv_chunk=32, scan_chunk=32,
-                  loss_chunk=64)
+    model = Model(cfg, remat=False, scan_chunk=32, loss_chunk=64)
     params = model.init(jax.random.PRNGKey(0))
     ds = SyntheticLMDataset(cfg.vocab_size, 64, seed=0)
     dc_cfg = DCS3GDConfig(learning_rate=0.05)

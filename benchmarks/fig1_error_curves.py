@@ -26,8 +26,7 @@ def run_curve(algo: str, n_workers: int, steps: int = 60, bpw: int = 4,
               seq: int = 64, lr: float = 0.3,
               reducer: str = "mean_allreduce"):
     cfg = reduced(get_config("qwen3-0.6b"))
-    model = Model(cfg, remat=False, q_chunk=32, kv_chunk=32, scan_chunk=32,
-                  loss_chunk=64)
+    model = Model(cfg, remat=False, scan_chunk=32, loss_chunk=64)
     params = model.init(jax.random.PRNGKey(0))
     ds = SyntheticLMDataset(cfg.vocab_size, seq, seed=0)
     dc_cfg = DCS3GDConfig(learning_rate=lr, momentum=0.9, lambda0=0.2,

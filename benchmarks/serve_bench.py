@@ -141,7 +141,7 @@ def main(args=None):
     pages = slots * max_pages + 1 + max_pages  # headroom: no preemption
 
     cfg = reduced(get_config("qwen3-0.6b"))
-    model = Model(cfg, remat=False, q_chunk=16, kv_chunk=16, scan_chunk=16)
+    model = Model(cfg, remat=False, scan_chunk=16)
     params = model.init(jax.random.PRNGKey(0))
     engine = Engine(model)
 

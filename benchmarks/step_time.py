@@ -256,8 +256,7 @@ def main(args=None):
     W, bpw, seq = 2, 2, 32
 
     cfg = reduced(get_config("qwen3-0.6b"))
-    model = Model(cfg, remat=False, q_chunk=16, kv_chunk=16, scan_chunk=16,
-                  loss_chunk=64)
+    model = Model(cfg, remat=False, scan_chunk=16, loss_chunk=64)
     data = SyntheticLMDataset(cfg.vocab_size, seq, seed=0)
 
     algos = [a for a in requested_algos(args, default=FULL_ALGOS)

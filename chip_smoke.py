@@ -159,8 +159,7 @@ def serve(phase: str, use_kernel: bool) -> tuple:
     reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, n).tolist(),
                     max_new=32) for i, n in enumerate(lens)]
     with Probe() as probe:
-        model = Model(cfg, remat=False, q_chunk=64, kv_chunk=64,
-                      scan_chunk=64)
+        model = Model(cfg, remat=False, scan_chunk=64)
         params = model.init(jax.random.PRNGKey(SEED))
         sch = Scheduler(model, params, slots=8, pages=161, page_size=16,
                         max_len=320, decode_burst=4, use_kernel=use_kernel)

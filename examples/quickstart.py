@@ -19,8 +19,7 @@ from repro.models.transformer import Model
 def main():
     # 1. pick an architecture (any of the 10 assigned ones) at smoke scale
     cfg = reduced(get_config("qwen3-0.6b"))
-    model = Model(cfg, remat=False, q_chunk=32, kv_chunk=32, scan_chunk=32,
-                  loss_chunk=64)
+    model = Model(cfg, remat=False, scan_chunk=32, loss_chunk=64)
     params = model.init(jax.random.PRNGKey(0))
     print(f"model: {cfg.name}, "
           f"{sum(x.size for x in jax.tree.leaves(params))/1e6:.1f}M params")

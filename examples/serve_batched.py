@@ -20,7 +20,7 @@ from repro.models.transformer import Model
 
 def demo(arch: str, gen: int = 8):
     cfg = reduced(get_config(arch))
-    model = Model(cfg, remat=False, q_chunk=32, kv_chunk=32, scan_chunk=32)
+    model = Model(cfg, remat=False, scan_chunk=32)
     key = jax.random.PRNGKey(0)
     params = model.init(key)
     prompts = jax.random.randint(key, (4, 16), 0, cfg.vocab_size)
@@ -58,7 +58,7 @@ def demo_continuous(arch: str = "qwen3-0.6b"):
     mid-flight, pages recycle through the pool."""
     from repro.serve import Request, Scheduler
     cfg = reduced(get_config(arch))
-    model = Model(cfg, remat=False, q_chunk=32, kv_chunk=32, scan_chunk=32)
+    model = Model(cfg, remat=False, scan_chunk=32)
     params = model.init(jax.random.PRNGKey(0))
     rng = jax.random.PRNGKey(1)
     reqs = [Request(rid=i,

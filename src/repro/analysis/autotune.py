@@ -145,8 +145,7 @@ def probe_train(candidates: List[dict], *, model=None, algo: str = "dc_s3gd",
 
     if model is None:
         cfg = reduced(get_config("qwen3-0.6b"))
-        model = Model(cfg, remat=False, q_chunk=16, kv_chunk=16,
-                      scan_chunk=16, loss_chunk=64)
+        model = Model(cfg, remat=False, scan_chunk=16, loss_chunk=64)
     data = SyntheticLMDataset(model.cfg.vocab_size, seq, seed=0)
     dc_cfg = DCS3GDConfig(learning_rate=0.05, momentum=0.9, lambda0=0.2,
                           warmup_steps=1, total_steps=max(steps, 2))
@@ -193,8 +192,7 @@ def probe_serve(candidates: List[dict], *, model=None, params=None,
 
     if model is None:
         cfg = reduced(get_config("qwen3-0.6b"))
-        model = Model(cfg, remat=False, q_chunk=16, kv_chunk=16,
-                      scan_chunk=16)
+        model = Model(cfg, remat=False, scan_chunk=16)
     if params is None:
         params = model.init(jax.random.PRNGKey(0))
     vocab = model.cfg.vocab_size

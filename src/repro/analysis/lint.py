@@ -134,8 +134,7 @@ def _transformer_setup():
     from repro.models.transformer import Model
 
     cfg = reduced(get_config("qwen3-0.6b"))
-    model = Model(cfg, remat=False, q_chunk=16, kv_chunk=16,
-                  scan_chunk=16, loss_chunk=64)
+    model = Model(cfg, remat=False, scan_chunk=16, loss_chunk=64)
     data = SyntheticLMDataset(cfg.vocab_size, 32, seed=0)
     return model, worker_batches(data, 0, N_WORKERS, 2)
 

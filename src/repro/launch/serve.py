@@ -239,7 +239,7 @@ def main(argv=None):
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
-    model = Model(cfg, remat=False, q_chunk=64, kv_chunk=64, scan_chunk=64)
+    model = Model(cfg, remat=False, scan_chunk=64)
     engine = Engine(model)
     key = jax.random.PRNGKey(args.seed)
     if args.train_ckpt is not None:

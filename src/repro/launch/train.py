@@ -185,7 +185,7 @@ def run(args) -> dict:
     if args.reduced:
         cfg = reduced(cfg)
     model = Model(cfg, remat=True, moe_dense=args.reduced,
-                  q_chunk=64, kv_chunk=64, scan_chunk=64, loss_chunk=256)
+                  scan_chunk=64, loss_chunk=256)
 
     # an explicit --workers on resume is an elastic-resume request: the
     # state is restored at the CHECKPOINT's count, then resharded

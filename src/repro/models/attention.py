@@ -1,17 +1,30 @@
 """Attention variants: GQA (+qk-norm, sliding/local window), MLA, cross-attn.
 
-Two execution paths per variant:
-  * ``*_train``  — full-sequence, memory-blocked (flash-style online softmax
-    over KV blocks inside a scan over Q chunks) so 32k prefill fits;
+Execution paths per variant:
+  * ``*_train`` — full sequence, training and `Model.prefill`: the custom
+    VJP `flash_attention` (FlashAttention-2 in XLA).  Its forward is an
+    online softmax over (query tile, key tile) pairs and saves only q, k,
+    v, the output and the f32 row log-sum-exp; its backward recomputes
+    each tile's probabilities from them.  Memory is O(S·H) plus one
+    tile, not O(S²·H), so 32k prefill fits; tiles the causal or window
+    mask empties are skipped in both directions;
+  * ``*_prefill_chunk`` — a prompt chunk against the paged cache, through
+    `_blocked_attention` at a page-aligned block (bitwise independent of
+    where chunks fall; forward only);
   * ``*_decode`` — one new token against a KV cache (linear in cache length,
     ring-buffer variant for sliding-window archs).
+
+`_blocked_attention` (nested scans, differentiable by autodiff) is also
+the oracle the VJP is tested against.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import random
 
 from repro.models.layers import apply_mrope, apply_rope, dense_init, init_rmsnorm, rmsnorm
@@ -45,7 +58,8 @@ def init_cross_attention(key, d: int, n_heads: int, head_dim: int, dtype) -> dic
 
 
 # ---------------------------------------------------------------------------
-# flash-style blocked attention core (pure jnp; ref for the Pallas kernel)
+# flash-style blocked attention core (pure jnp): the serving chunk path, and
+# the oracle of `flash_attention` and of the Pallas kernel
 # ---------------------------------------------------------------------------
 
 
@@ -113,6 +127,202 @@ def _blocked_attention(q, k, v, q_pos, k_pos, *, causal: bool, window: int,
 
 
 # ---------------------------------------------------------------------------
+# training attention: tiled forward + recomputing backward (custom VJP)
+# ---------------------------------------------------------------------------
+
+TILE = 512  # most rows of a tile (tuned on a v5e at S = 2048)
+
+
+def tile_rows(S: int) -> int:
+    """Rows (and key columns) of one tile for a sequence of ``S``: all of
+    S when it fits one tile, else S split evenly into tiles of at most
+    ``TILE`` rows, each rounded up to a multiple of 128."""
+    n = -(-S // TILE)
+    return S if n == 1 else -(-S // (n * 128)) * 128
+
+
+def live_tiles(n_q: int, n_k: int, t: int, *, causal: bool,
+               window: int) -> np.ndarray:
+    """(n_q, n_k) bool: the (query tile, key tile) pairs the mask does not
+    empty.  Under ``causal`` none above the diagonal; under ``window > 0``
+    none whose nearest pair is ``window`` or more apart.  Read by index,
+    so valid for self-attention over positions that increase along the
+    sequence (n indices apart is at least n positions apart)."""
+    i, j = np.arange(n_q)[:, None], np.arange(n_k)[None, :]
+    live = np.ones((n_q, n_k), bool)
+    if causal:
+        live &= j <= i
+    if window > 0:
+        live &= (i - j - 1) * t + 1 < window
+    return live
+
+
+def _span(row: np.ndarray):
+    """[first, last + 1) of a row or column of `live_tiles`: one run,
+    never empty (the diagonal tile, or every tile without a mask)."""
+    idx = np.flatnonzero(row)
+    return int(idx[0]), int(idx[-1]) + 1
+
+
+def _tile_of(x, i, t, axis=1):
+    return jax.lax.dynamic_slice_in_dim(x, i * t, t, axis)
+
+
+class _Tiles:
+    """The operands padded to whole tiles of ``t`` rows, which tiles are
+    live, and each tile's scaled, masked scores (f32)."""
+
+    def __init__(self, q, k, v, positions, causal, window, t):
+        Sq, Sk = q.shape[1], k.shape[1]
+        if (causal or window > 0) and Sq != Sk:
+            raise ValueError("a causal or windowed mask needs self-attention")
+        self.n_q, self.n_k = -(-Sq // t), -(-Sk // t)
+        self.Sq, self.Sk, self.t = Sq, Sk, t
+        self.causal, self.window = causal, window
+        self.scale = q.shape[-1] ** -0.5
+        self.q = self.pad(q)
+        self.k, self.v = self.pad(k), self.pad(v)
+        self.pos = jnp.pad(positions, (0, max(self.n_q, self.n_k) * t
+                                       - positions.shape[0]))
+        self.live = live_tiles(self.n_q, self.n_k, t, causal=causal,
+                               window=window)
+
+    def pad(self, x, axis=1):
+        n = -(-x.shape[axis] // self.t) * self.t - x.shape[axis]
+        return jnp.pad(x, [(0, n if a == axis else 0) for a in range(x.ndim)])
+
+    def scores(self, qi, kj, i, j):
+        """qi (B, t, KV, G, hd) of query tile i against kj of key tile j:
+        (B, KV, G, t, t), masked entries at NEG_INF."""
+        t = self.t
+        s = jnp.einsum("bqkgh,bckh->bkgqc", qi, kj,
+                       preferred_element_type=jnp.float32) * self.scale
+        qp = _tile_of(self.pos, i, t, 0)[:, None]
+        kp = _tile_of(self.pos, j, t, 0)[None, :]
+        ok = (j * t + jnp.arange(t) < self.Sk)[None, :]
+        if self.causal:
+            ok = ok & (qp >= kp)
+        if self.window > 0:
+            ok = ok & (qp - kp < self.window)
+        return jnp.where(ok[None, None, None], s, NEG_INF)
+
+
+def _flash_forward(q, k, v, positions, causal, window, t):
+    """Online softmax, row by row over each query tile's live key tiles.
+    Returns the output (B, Sq, KV, G, vd) in q's dtype and the row
+    log-sum-exp (B, KV, G, Sq) in f32."""
+    B, _, KV, G, _ = q.shape
+    vd = v.shape[-1]
+    T = _Tiles(q, k, v, positions, causal, window, t)
+    outs, lses = [], []
+    for i in range(T.n_q):
+        qi = T.q[:, i * t:(i + 1) * t]
+
+        def step(j, carry, i=i, qi=qi):
+            m, l, acc = carry
+            s = T.scores(qi, _tile_of(T.k, j, t), i, j)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            p = jnp.exp(s - m_new[..., None])
+            corr = jnp.exp(m - m_new)
+            pv = jnp.einsum("bkgqc,bckh->bkgqh", p.astype(v.dtype),
+                            _tile_of(T.v, j, t),
+                            preferred_element_type=jnp.float32)
+            return (m_new, l * corr + jnp.sum(p, axis=-1),
+                    acc * corr[..., None] + pv)
+
+        init = (jnp.full((B, KV, G, t), NEG_INF, jnp.float32),
+                jnp.zeros((B, KV, G, t), jnp.float32),
+                jnp.zeros((B, KV, G, t, vd), jnp.float32))
+        m, l, acc = jax.lax.fori_loop(*_span(T.live[i]), step, init)
+        l = jnp.maximum(l, 1e-30)
+        outs.append((acc / l[..., None]).astype(q.dtype))
+        lses.append(m + jnp.log(l))
+    out = jnp.concatenate(outs, axis=3).transpose(0, 3, 1, 2, 4)
+    return out[:, :T.Sq], jnp.concatenate(lses, axis=3)[..., :T.Sq]
+
+
+def _flash_backward(q, k, v, positions, out, lse, d_out, causal, window, t):
+    """FlashAttention-2's backward, column by column over each key tile's
+    live query tiles: a tile's probabilities are recomputed from the
+    saved log-sum-exp; dK and dV gather in the column's carry, dQ in a
+    buffer over the whole sequence."""
+    T = _Tiles(q, k, v, positions, causal, window, t)
+    delta = T.pad(jnp.einsum("bqkgh,bqkgh->bkgq", d_out.astype(jnp.float32),
+                             out.astype(jnp.float32)), 3)
+    lse = T.pad(lse, 3)
+    d_out = T.pad(d_out)
+    dq = jnp.zeros(T.q.shape, jnp.float32)
+    dks, dvs = [], []
+    for j in range(T.n_k):
+        kj, vj = T.k[:, j * t:(j + 1) * t], T.v[:, j * t:(j + 1) * t]
+
+        def step(i, carry, j=j, kj=kj, vj=vj):
+            dk, dv, dq = carry
+            qi, doi = _tile_of(T.q, i, t), _tile_of(d_out, i, t)
+            p = jnp.exp(T.scores(qi, kj, i, j)
+                        - _tile_of(lse, i, t, 3)[..., None])
+            dp = jnp.einsum("bqkgh,bckh->bkgqc", doi, vj,
+                            preferred_element_type=jnp.float32)
+            ds = p * (dp - _tile_of(delta, i, t, 3)[..., None])
+            ds = ds.astype(q.dtype)
+            dv = dv + jnp.einsum("bkgqc,bqkgh->bckh", p.astype(q.dtype), doi,
+                                 preferred_element_type=jnp.float32)
+            dk = dk + jnp.einsum("bkgqc,bqkgh->bckh", ds, qi,
+                                 preferred_element_type=jnp.float32) * T.scale
+            dq_i = jnp.einsum("bkgqc,bckh->bqkgh", ds, kj,
+                              preferred_element_type=jnp.float32) * T.scale
+            dq = jax.lax.dynamic_update_slice_in_dim(
+                dq, _tile_of(dq, i, t) + dq_i, i * t, 1)
+            return dk, dv, dq
+
+        init = (jnp.zeros(kj.shape, jnp.float32),
+                jnp.zeros(vj.shape, jnp.float32), dq)
+        dk, dv, dq = jax.lax.fori_loop(*_span(T.live[:, j]), step, init)
+        dks.append(dk)
+        dvs.append(dv)
+    dk = jnp.concatenate(dks, axis=1)
+    dv = jnp.concatenate(dvs, axis=1)
+    return dq[:, :T.Sq], dk[:, :T.Sk], dv[:, :T.Sk]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash(q, k, v, positions, causal, window, t):
+    return _flash_forward(q, k, v, positions, causal, window, t)[0]
+
+
+def _flash_fwd(q, k, v, positions, causal, window, t):
+    out, lse = _flash_forward(q, k, v, positions, causal, window, t)
+    return out, (q, k, v, positions, out, lse)
+
+
+def _flash_bwd(causal, window, t, res, d_out):
+    q, k, v, positions, out, lse = res
+    dq, dk, dv = _flash_backward(q, k, v, positions, out, lse, d_out,
+                                 causal, window, t)
+    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype), None
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def flash_attention(q, k, v, positions, *, causal: bool,
+                    window: int) -> jnp.ndarray:
+    """Training attention.  q: (B, Sq, KV, G, hd); k: (B, Sk, KV, hd); v:
+    (B, Sk, KV, vd); ``positions`` (Sq,) of the queries, and of the keys
+    where the mask reads them (causal or windowed self-attention, Sq ==
+    Sk).  Returns (B, Sq, KV, G, vd) in q's dtype.
+
+    FlashAttention-2 in XLA: the forward keeps only q, k, v, the output
+    and the row log-sum-exp for the backward, which recomputes each
+    tile's probabilities from them, so memory is O(S·H) and one tile,
+    not O(S²·H); neither pass visits a tile the mask empties
+    (`live_tiles`); tiles are `tile_rows` of the longer sequence.
+    `_blocked_attention` is its oracle."""
+    t = tile_rows(max(q.shape[1], k.shape[1]))
+    return _flash(q, k, v, positions, causal, window, t)
+
+
+# ---------------------------------------------------------------------------
 # GQA train / prefill forward
 # ---------------------------------------------------------------------------
 
@@ -122,8 +332,8 @@ def attention_train(params: dict, x: jnp.ndarray, positions: jnp.ndarray, *,
                     qk_norm: bool = False, norm_eps: float = 1e-6,
                     mrope_positions: Optional[jnp.ndarray] = None,
                     mrope_sections: Optional[Tuple[int, int, int]] = None,
-                    kv_override: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
-                    q_chunk: int = 512, kv_chunk: int = 1024) -> jnp.ndarray:
+                    kv_override: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None
+                    ) -> jnp.ndarray:
     """x: (B, S, d); positions: (S,) absolute positions.  Returns (B, S, d).
 
     ``kv_override`` supplies external (k, v) for cross-attention (already
@@ -134,10 +344,8 @@ def attention_train(params: dict, x: jnp.ndarray, positions: jnp.ndarray, *,
     if kv_override is None:
         k = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
         v = jnp.einsum("bsd,dhk->bshk", x, params["wv"])
-        k_pos = positions
     else:
         k, v = kv_override
-        k_pos = jnp.arange(k.shape[1])
     if qk_norm:
         q = rmsnorm(params["q_norm"], q, norm_eps)
         k = rmsnorm(params["k_norm"], k, norm_eps) if kv_override is None else k
@@ -152,9 +360,7 @@ def attention_train(params: dict, x: jnp.ndarray, positions: jnp.ndarray, *,
     H, KV = q.shape[2], k.shape[2]
     G = H // KV
     qg = q.reshape(B, S, KV, G, q.shape[-1])
-    out = _blocked_attention(qg, k, v, positions, k_pos, causal=causal,
-                             window=window, q_chunk=min(q_chunk, S),
-                             kv_chunk=min(kv_chunk, k.shape[1]))
+    out = flash_attention(qg, k, v, positions, causal=causal, window=window)
     out = out.reshape(B, S, H, -1)
     return jnp.einsum("bshk,hkd->bsd", out, params["wo"])
 
@@ -358,8 +564,7 @@ def init_mla(key, d: int, n_heads: int, mla_cfg, dtype) -> dict:
 
 
 def mla_train(params: dict, x: jnp.ndarray, positions: jnp.ndarray, *,
-              mla_cfg, rope_theta: float, norm_eps: float = 1e-6,
-              q_chunk: int = 512, kv_chunk: int = 1024) -> jnp.ndarray:
+              mla_cfg, rope_theta: float, norm_eps: float = 1e-6) -> jnp.ndarray:
     m = mla_cfg
     B, S, _ = x.shape
     q_lat = rmsnorm(params["q_norm"], x @ params["w_dq"], norm_eps)
@@ -378,9 +583,7 @@ def mla_train(params: dict, x: jnp.ndarray, positions: jnp.ndarray, *,
     k_full = jnp.concatenate([k_nope, k_rope_b], axis=-1)
     # MHA (KV = H groups of 1)
     qg = q_full[:, :, :, None, :]
-    out = _blocked_attention(qg, k_full, v, positions, positions, causal=True,
-                             window=0, q_chunk=min(q_chunk, S),
-                             kv_chunk=min(kv_chunk, S))
+    out = flash_attention(qg, k_full, v, positions, causal=True, window=0)
     out = out.reshape(B, S, H, m.v_head_dim)
     return jnp.einsum("bshk,hkd->bsd", out, params["wo"])
 

@@ -167,9 +167,7 @@ def _apply_block(kind: str, cfg: ModelConfig, p: dict, x, ctx: dict,
             if kind == "mla":
                 h = attn.mla_train(p["attn"], h, positions, mla_cfg=cfg.mla,
                                    rope_theta=cfg.rope_theta,
-                                   norm_eps=cfg.norm_eps,
-                                   q_chunk=ctx["q_chunk"],
-                                   kv_chunk=ctx["kv_chunk"])
+                                   norm_eps=cfg.norm_eps)
                 if collect_cache:
                     cache_entry = _mla_cache_from_seq(p, cfg, x, positions,
                                                       ctx)
@@ -179,8 +177,7 @@ def _apply_block(kind: str, cfg: ModelConfig, p: dict, x, ctx: dict,
                     window=window, qk_norm=cfg.qk_norm, norm_eps=cfg.norm_eps,
                     mrope_positions=ctx.get("mrope_positions"),
                     mrope_sections=cfg.vlm.mrope_sections if cfg.vlm
-                    else None,
-                    q_chunk=ctx["q_chunk"], kv_chunk=ctx["kv_chunk"])
+                    else None)
                 if collect_cache:
                     cache_entry = _kv_cache_from_seq(
                         p, cfg, _norm(cfg, p["ln1"], x), positions, window,
@@ -194,9 +191,7 @@ def _apply_block(kind: str, cfg: ModelConfig, p: dict, x, ctx: dict,
                 xv = jnp.einsum("bsd,dhk->bshk", enc, p["xattn"]["wv"])
                 h = attn.attention_train(p["xattn"], h, positions,
                                          rope_theta=0.0, causal=False,
-                                         kv_override=(xk, xv),
-                                         q_chunk=ctx["q_chunk"],
-                                         kv_chunk=ctx["kv_chunk"])
+                                         kv_override=(xk, xv))
             x = x + h
             if collect_cache:
                 cache_entry = dict(cache_entry or {}, xk=xk, xv=xv)
@@ -415,15 +410,12 @@ class Model:
     """Functional model wrapper: all methods are pure and jit/vmap friendly."""
 
     def __init__(self, cfg: ModelConfig, *, remat: bool = True,
-                 moe_dense: bool = False, q_chunk: int = 512,
-                 kv_chunk: int = 1024, scan_chunk: int = 256,
+                 moe_dense: bool = False, scan_chunk: int = 256,
                  loss_chunk: int = 2048, seq_parallel: bool = False):
         self.cfg = cfg
         self.remat = remat
         self.moe_dense = moe_dense
         self.seq_parallel = seq_parallel
-        self.q_chunk = q_chunk
-        self.kv_chunk = kv_chunk
         self.scan_chunk = scan_chunk
         self.loss_chunk = loss_chunk
         self.stages = stage_plan(cfg)
@@ -488,8 +480,6 @@ class Model:
 
     def _ctx(self, S, extra=None):
         ctx = {
-            "q_chunk": min(self.q_chunk, S),
-            "kv_chunk": min(self.kv_chunk, S),
             "scan_chunk": min(self.scan_chunk, S),
             "moe_dense": self.moe_dense,
         }
@@ -517,15 +507,11 @@ class Model:
         x = x + sinusoidal_positions(x.shape[1], cfg.d_model
                                      ).astype(self.compute_dtype)[None]
         positions = jnp.arange(x.shape[1])
-        ctx = self._ctx(x.shape[1])
-        ctx["positions"] = positions
         # encoder attention is bidirectional; inline the unit here
         def bidir_body(carry, p):
             h = _norm(cfg, p["ln1"], carry)
             h = attn.attention_train(p["attn"], h, positions,
-                                     rope_theta=0.0, causal=False,
-                                     q_chunk=ctx["q_chunk"],
-                                     kv_chunk=ctx["kv_chunk"])
+                                     rope_theta=0.0, causal=False)
             carry = carry + h
             h = _norm(cfg, p["ln2"], carry)
             carry = carry + mlp(p["mlp"], h, cfg.activation)

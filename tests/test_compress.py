@@ -483,8 +483,7 @@ def test_compressed_state_specs_on_multipod_mesh():
     from repro.models.transformer import Model
 
     mcfg = reduced(get_config("qwen3-0.6b"))
-    model = Model(mcfg, remat=False, q_chunk=8, kv_chunk=8, scan_chunk=8,
-                  loss_chunk=8)
+    model = Model(mcfg, remat=False, scan_chunk=8, loss_chunk=8)
     alg = registry.make("dc_s3gd", CFG, n_workers=32,
                         reducer=PowerSGDReduce(rank=2), buckets=4)
     state = jax.eval_shape(alg.init, S.abstract_params(model))

@@ -30,8 +30,7 @@ def _is_p(x):
 
 def _reduced_model():
     cfg = reduced(get_config("qwen3-0.6b"))
-    return cfg, Model(cfg, remat=False, q_chunk=8, kv_chunk=8, scan_chunk=8,
-                      loss_chunk=8)
+    return cfg, Model(cfg, remat=False, scan_chunk=8, loss_chunk=8)
 
 
 # ---------------------------------------------------------------------------

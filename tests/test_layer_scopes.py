@@ -35,8 +35,7 @@ def _program(algo: str, buckets: int, use_kernels: bool) -> lint.Program:
     recompute lies under ``transpose(...)``) and the given tail."""
     prog = lint.Program(lint.GridPoint(algo, "mean_allreduce", buckets,
                                        False), model="transformer")
-    prog.model = Model(prog.model.cfg, remat=True, q_chunk=8, kv_chunk=8,
-                       scan_chunk=8, loss_chunk=16)
+    prog.model = Model(prog.model.cfg, remat=True, scan_chunk=8, loss_chunk=16)
     prog.alg = registry.make(algo, prog.cfg, n_workers=lint.N_WORKERS,
                              reducer="mean_allreduce", buckets=buckets,
                              use_kernels=use_kernels)

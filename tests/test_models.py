@@ -19,8 +19,6 @@ from helpers import ALL_ARCHS, make_lm_batch
 
 def _model(cfg, **kw):
     kw.setdefault("remat", False)
-    kw.setdefault("q_chunk", 8)
-    kw.setdefault("kv_chunk", 8)
     kw.setdefault("scan_chunk", 8)
     kw.setdefault("loss_chunk", 8)
     return Model(cfg, **kw)
@@ -186,8 +184,7 @@ def test_published_config_traces_in_mixed_precision(entry):
     once, so the per-layer scan carry keeps one dtype and the full-width
     program traces (shapes only: nothing is allocated)."""
     cfg = get_config("qwen3-0.6b")
-    m = _model(cfg, remat=True, q_chunk=64, kv_chunk=64, scan_chunk=64,
-               loss_chunk=256)
+    m = _model(cfg, remat=True, scan_chunk=64, loss_chunk=256)
     params = jax.eval_shape(m.init, random.PRNGKey(0))
     assert {x.dtype for x in jax.tree.leaves(params)} == {jnp.dtype("float32")}
     assert m.compute_dtype == jnp.bfloat16

@@ -26,8 +26,7 @@ def _model(arch):
     if cfg.rglru is not None:
         cfg = dataclasses.replace(
             cfg, rglru=dataclasses.replace(cfg.rglru, attention_window=8))
-    return Model(cfg, remat=False, q_chunk=16, kv_chunk=16, scan_chunk=16,
-                 loss_chunk=16)
+    return Model(cfg, remat=False, scan_chunk=16, loss_chunk=16)
 
 
 def _prompts(vocab, seed=0):

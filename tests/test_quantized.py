@@ -134,8 +134,7 @@ def _serve_model():
     from repro.configs import get_config, reduced
     from repro.models.transformer import Model
     cfg = reduced(get_config("qwen3-0.6b"))
-    model = Model(cfg, remat=False, q_chunk=16, kv_chunk=16, scan_chunk=16,
-                  loss_chunk=16)
+    model = Model(cfg, remat=False, scan_chunk=16, loss_chunk=16)
     return cfg, model, model.init(jax.random.PRNGKey(0))
 
 

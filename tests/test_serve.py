@@ -34,8 +34,7 @@ def _model(arch):
         # (window) are the same size — a precondition for bitwise parity
         cfg = dataclasses.replace(
             cfg, rglru=dataclasses.replace(cfg.rglru, attention_window=8))
-    return Model(cfg, remat=False, q_chunk=16, kv_chunk=16, scan_chunk=16,
-                 loss_chunk=16)
+    return Model(cfg, remat=False, scan_chunk=16, loss_chunk=16)
 
 
 # ---------------------------------------------------------------------------
@@ -483,7 +482,7 @@ def test_scheduler_rejects_encoder_decoder_archs_clearly():
     inputs the scheduler has no seam for yet; fail loudly at
     construction, not with a KeyError mid-prefill."""
     m = Model(reduced(get_config("whisper-large-v3")), remat=False,
-              q_chunk=16, kv_chunk=16, scan_chunk=16)
+              scan_chunk=16)
     params = None  # never reached
     with pytest.raises(NotImplementedError):
         Scheduler(m, params, slots=1, pages=8, page_size=8, max_len=32)

@@ -117,8 +117,7 @@ def test_small_mesh_end_to_end_jit():
     from repro.configs import reduced
     from repro.launch.engine import Engine
     cfg = reduced(get_config("qwen3-0.6b"))
-    model = Model(cfg, remat=False, q_chunk=8, kv_chunk=8, scan_chunk=8,
-                  loss_chunk=8)
+    model = Model(cfg, remat=False, scan_chunk=8, loss_chunk=8)
     mesh = jax.make_mesh((1, 1), ("data", "model"))
     dc_cfg = DCS3GDConfig(learning_rate=0.01)
     alg = registry.make("dc_s3gd", dc_cfg, n_workers=2)

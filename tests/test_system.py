@@ -74,7 +74,7 @@ def test_compile_cache_dir_rule(monkeypatch, tmp_path, env_set):
 
 def test_serve_generate_greedy_deterministic():
     cfg = reduced(get_config("qwen3-0.6b"))
-    m = Model(cfg, remat=False, q_chunk=16, kv_chunk=16, scan_chunk=16)
+    m = Model(cfg, remat=False, scan_chunk=16)
     params = m.init(jax.random.PRNGKey(0))
     prompts = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0,
                                  cfg.vocab_size)
@@ -89,7 +89,7 @@ def test_serve_generate_scan_matches_per_token_loop():
     """The single-trace `lax.scan` decode loop must reproduce the
     dispatch-per-token reference exactly, for both samplers."""
     cfg = reduced(get_config("qwen3-0.6b"))
-    m = Model(cfg, remat=False, q_chunk=16, kv_chunk=16, scan_chunk=16)
+    m = Model(cfg, remat=False, scan_chunk=16)
     params = m.init(jax.random.PRNGKey(0))
     prompts = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0,
                                  cfg.vocab_size)
@@ -124,7 +124,7 @@ def test_serve_generate_scan_matches_per_token_loop():
 
 def test_serve_generate_ssm():
     cfg = reduced(get_config("falcon-mamba-7b"))
-    m = Model(cfg, remat=False, q_chunk=16, kv_chunk=16, scan_chunk=16)
+    m = Model(cfg, remat=False, scan_chunk=16)
     params = m.init(jax.random.PRNGKey(0))
     prompts = jax.random.randint(jax.random.PRNGKey(1), (1, 6), 0,
                                  cfg.vocab_size)

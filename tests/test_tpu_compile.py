@@ -125,8 +125,7 @@ def test_train_step_fits_one_chip(one_chip, monkeypatch, use_kernels):
     monkeypatch.setattr(ops, "_is_cpu", lambda: False)
     cfg = get_config(ARCH)
     # the trainer's model (launch/train.py `run`)
-    model = Model(cfg, remat=True, q_chunk=64, kv_chunk=64, scan_chunk=64,
-                  loss_chunk=256)
+    model = Model(cfg, remat=True, scan_chunk=64, loss_chunk=256)
     dc = DCS3GDConfig()
     alg = registry.make("dc_s3gd", dc, n_workers=1,
                         reducer=registry.make_reducer("mean_allreduce", dc),
