@@ -2,11 +2,13 @@
 the compile cache, seeds, and the result line.
 
 A cell (`workloads` entry of ``BENCHMARK.json``) names a configuration
-(``bench/configs/<file>``), a traffic mix (``bench/traffic/<traffic>.json``,
-whose ``kind`` picks the module ``bench/<kind>.py``) and its chip
-count.  Per-layer metrics are readers ``bench/metrics/<name>.py`` and
-correctness limits ``bench/limits/<workload>.json``; nothing here names a
-cell.
+(``bench/configs/<file>``, whose ``family`` picks the module
+``bench/families/<family>.py``), a traffic mix
+(``bench/traffic/<traffic>.json``, whose ``kind`` picks the module
+``bench/<kind>.py``) and its chip count.  Per-layer metrics are readers
+``bench/metrics/<name>.py`` and correctness limits
+``bench/limits/<workload>.json``; nothing here names a cell or an
+architecture.
 """
 from __future__ import annotations
 
@@ -49,7 +51,26 @@ def load_traffic(name: str) -> dict:
 
 
 def load_config(entry: dict, root: Path = ROOT) -> dict:
-    return json.loads((root / entry["file"]).read_text())
+    """A configuration file; one whose family has no module fails
+    here."""
+    conf = json.loads((root / entry["file"]).read_text())
+    family(conf)
+    return conf
+
+
+def family(conf: dict):
+    """The module ``bench/families/<family>.py`` of the family a
+    configuration file names under ``"family"``, which has no default:
+    what the benchmark knows of the architecture (its interface:
+    `families.decoder`)."""
+    name = conf["family"]
+    try:
+        return importlib.import_module(f"families.{name}")
+    except ModuleNotFoundError as e:
+        if e.name not in ("families", f"families.{name}"):
+            raise
+        raise ValueError(f"{conf['name']}: no module for family {name!r}; "
+                         f"add bench/families/{name}.py") from None
 
 
 def load_limits(workload: str) -> dict:

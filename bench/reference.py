@@ -1,29 +1,24 @@
-"""The plain reference: a float32 decoder in `jax.numpy` and DC-S3GD's
-update from the paper's equations.  Imports nothing of the program.
+"""The plain reference of what does not depend on the architecture:
+DC-S3GD's update from the paper's equations, over the per-sequence loss
+of the configuration's family (`families/`), and the fp8 control's
+matrix product.  Imports nothing of the program.
 
-It reads weights in the layout the benchmark makes them (`model.py`):
-``embed.tok`` (V, d), ``unembed`` (d, V), ``final_norm``, and the layer
-stack under ``stage0.b0`` with a leading layer axis.  Matrix products
-run at ``Precision.HIGHEST``; ``lowp=True`` rounds every matrix-product
-operand, and in training every product's output gradient, to fp8 with a
-per-tensor scale (e4m3 forward, e5m2 backward): the control that has to
-come out as not correct.
+Matrix products run at ``Precision.HIGHEST``; ``lowp=True`` rounds every
+matrix-product operand, and in training every product's output
+gradient, to fp8 with a per-tensor scale (e4m3 forward, e5m2
+backward): the control that has to come out as not correct.
 
-Departures that the program makes and the reference follows, because
-they are part of the configuration as it is run: the vocabulary padded
-to a multiple of 256 joins the training softmax (decode ignores the
-padded columns), and weight decay reaches every per-worker leaf of rank
-above one, the layer-stacked norm scales included.
+A departure that the program makes and the reference follows, because
+it is part of the configuration as it is run: weight decay reaches
+every per-worker leaf of rank above one, the layer-stacked norm scales
+included.
 """
 from __future__ import annotations
-
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 
 HI = jax.lax.Precision.HIGHEST
-NEG = -1e30
 
 
 # ---------------------------------------------------------------------------
@@ -66,119 +61,15 @@ def matmul(lowp: bool):
     return mm
 
 
-# ---------------------------------------------------------------------------
-# the decoder
-# ---------------------------------------------------------------------------
-
-
-def _norm(p, x, rc):
-    x = x.astype(jnp.float32)
-    if rc["norm"] == "layernorm":
-        mu = jnp.mean(x, -1, keepdims=True)
-        var = jnp.mean(jnp.square(x - mu), -1, keepdims=True)
-        return (x - mu) / jnp.sqrt(var + rc["norm_eps"]) * p["scale"] \
-            + p["bias"]
-    var = jnp.mean(jnp.square(x), -1, keepdims=True)
-    return x / jnp.sqrt(var + rc["norm_eps"]) * p["scale"]
-
-
-def _rotary(x, pos, theta, dims):
-    """Rotate the first ``dims`` features of each head, pairing feature
-    i with i + dims/2 (x: (S, H, hd))."""
-    half = dims // 2
-    inv = 1.0 / theta ** (jnp.arange(0, dims, 2, dtype=jnp.float32) / dims)
-    ang = pos[:, None, None].astype(jnp.float32) * inv
-    c, s = jnp.cos(ang), jnp.sin(ang)
-    x1, x2, rest = x[..., :half], x[..., half:dims], x[..., dims:]
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s, rest], -1)
-
-
-def _layer(x, p, rc, mm):
-    """One decoder layer over one sequence (x: (S, d))."""
-    S = x.shape[0]
-    pos = jnp.arange(S)
-    a = p["attn"]
-    h = _norm(p["ln1"], x, rc)
-    q = mm("sd,dhk->shk", h, a["wq"])
-    k = mm("sd,dhk->shk", h, a["wk"])
-    v = mm("sd,dhk->shk", h, a["wv"])
-    if rc["qk_norm"]:
-        q = _norm(a["q_norm"], q, {**rc, "norm": "rmsnorm"})
-        k = _norm(a["k_norm"], k, {**rc, "norm": "rmsnorm"})
-    q = _rotary(q, pos, rc["rope_theta"], rc["rotary_dims"])
-    k = _rotary(k, pos, rc["rope_theta"], rc["rotary_dims"])
-    H, KV, hd = q.shape[1], k.shape[1], q.shape[2]
-    # query head j reads key/value head j // (H / KV)
-    k = jnp.repeat(k, H // KV, axis=1)
-    v = jnp.repeat(v, H // KV, axis=1)
-    s = mm("qhk,chk->hqc", q, k) / jnp.sqrt(jnp.float32(hd))
-    causal = pos[:, None] >= pos[None, :]
-    s = jnp.where(causal[None], s, NEG)
-    w = jax.nn.softmax(s, axis=-1)
-    o = mm("hqc,chk->qhk", w, v)
-    x = x + mm("shk,hkd->sd", o, a["wo"])
-    h = _norm(p["ln2"], x, rc)
-    m = p["mlp"]
-    up = mm("sd,df->sf", h, m["w_up"])
-    if "w_gate" in m:
-        up = jax.nn.silu(mm("sd,df->sf", h, m["w_gate"])) * up
-    else:
-        up = jax.nn.silu(up)
-    return x + mm("sf,fd->sd", up, m["w_down"])
-
-
-def hidden(params, tokens, rc, mm, *, remat: bool):
-    """Final-normed hidden states of one sequence: (S,) -> (S, d)."""
-    x = jnp.take(params["embed"]["tok"], tokens, axis=0).astype(jnp.float32)
-    body = partial(_layer, rc=rc, mm=mm)
-    if remat:
-        body = jax.checkpoint(body)
-    x, _ = jax.lax.scan(lambda c, p: (body(c, p), None), x,
-                        params["stage0"]["b0"])
-    return _norm(params["final_norm"], x, rc)
-
-
-def seq_nll(params, tokens, labels, rc, mm, chunk: int = 256):
-    """(sum of next-token losses, count) of one sequence; label -1 is
-    masked.  Logits are made ``chunk`` positions at a time."""
-    x = hidden(params, tokens, rc, mm, remat=True)
-    S = x.shape[0]
-    chunk = min(chunk, S)
-    n = S // chunk
-    xs = x.reshape(n, chunk, -1)
-    ls = labels.reshape(n, chunk)
-
-    @jax.checkpoint
-    def body(carry, xl):
-        xc, lc = xl
-        logits = mm("sd,dv->sv", xc, params["unembed"])
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, jnp.maximum(lc, 0)[:, None],
-                                   axis=-1)[:, 0]
-        valid = (lc >= 0).astype(jnp.float32)
-        return (carry[0] + jnp.sum((lse - gold) * valid),
-                carry[1] + jnp.sum(valid)), None
-
-    (tot, cnt), _ = jax.lax.scan(body, (jnp.float32(0), jnp.float32(0)),
-                                 (xs, ls))
-    return tot, cnt
-
-
-def batch_loss(params, tokens, labels, rc, mm):
+def batch_loss(params, tokens, labels, rc, mm, seq_loss):
     """Mean next-token loss over a worker's batch (B, S), one sequence
-    at a time."""
+    at a time; ``seq_loss`` is the family's."""
     def one(carry, tl):
-        t, c = seq_nll(params, tl[0], tl[1], rc, mm)
+        t, c = seq_loss(params, tl[0], tl[1], rc, mm)
         return (carry[0] + t, carry[1] + c), None
     (tot, cnt), _ = jax.lax.scan(one, (jnp.float32(0), jnp.float32(0)),
                                  (tokens, labels))
     return tot / jnp.maximum(cnt, 1.0)
-
-
-def logits(params, tokens, rc, mm):
-    """(S, V) logits of one sequence."""
-    x = hidden(params, tokens, rc, mm, remat=False)
-    return mm("sd,dv->sv", x, params["unembed"])
 
 
 # ---------------------------------------------------------------------------
@@ -199,7 +90,7 @@ def _sq(tree):
     return sum(jnp.sum(jnp.square(x)) for x in jax.tree.leaves(tree))
 
 
-def dc_step(w, m, lr_prev, batch, t, *, hp, rc, lowp=False,
+def dc_step(w, m, lr_prev, batch, t, *, hp, rc, seq_loss, lowp=False,
             exchange=True):
     """One DC-S3GD step of every worker (leading axis W on ``w``, ``m``
     and the batch).  The previous update is Δw_i = -lr_prev · m_i, so the
@@ -211,7 +102,8 @@ def dc_step(w, m, lr_prev, batch, t, *, hp, rc, lowp=False,
         m_i  = μ m_i + g̃_i + wd · w_i   (rank > 1)    (Eq. 11)
         w_i  = w_i + D_i − lr m_i                     (Eq. 12)
 
-    ``exchange=False`` leaves the mean out (D = 0): a planted fault.
+    ``seq_loss`` is the family's; ``exchange=False`` leaves the mean
+    out (D = 0): a planted fault.
     Returns (w, m, per-worker losses)."""
     mm = matmul(lowp)
     lr = schedule(t, hp["lr"], hp["warmup_steps"], hp["total_steps"])
@@ -225,7 +117,8 @@ def dc_step(w, m, lr_prev, batch, t, *, hp, rc, lowp=False,
         D = jax.tree.map(jnp.zeros_like, dw_prev)
 
     def worker(wi, mi, Di, toks, labs):
-        loss, g = jax.value_and_grad(batch_loss)(wi, toks, labs, rc, mm)
+        loss, g = jax.value_and_grad(batch_loss)(wi, toks, labs, rc, mm,
+                                                 seq_loss)
         c = jax.tree.map(lambda gg, d: gg * gg * d, g, Di)
         gn, cn = jnp.sqrt(_sq(g)), jnp.sqrt(_sq(c))
         lam = jnp.where(cn > 1e-30, hp["lambda0"] * gn / (cn + 1e-30), 0.0)

@@ -23,6 +23,11 @@ the self time under ``layer/pass``:
 Self times cover the whole trace, which starts and ends on a ready
 state: the traced window's device work.  A program that names none of
 its layers (the seed's, a serving cell) reads nothing.
+
+A reader of a part of a layer (a scope the program names inside one,
+such as the router of an MoE ``mlp``) asks for the ops whose name stack
+holds that segment (`step_ms`'s ``within``); the segment need not be
+one of `LAYERS`, so the split by layer stays as it is.
 """
 from __future__ import annotations
 
@@ -43,13 +48,20 @@ OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 NEAR = 64
 
 
+def segments(op_name: str) -> list:
+    """The segments of a name stack, each out of its transformations."""
+    out = []
+    for seg in op_name.split("/"):
+        m = WRAPPED.fullmatch(seg)
+        out.append(m.group(1) if m else seg)
+    return out
+
+
 def scope_of(op_name: str):
     """The innermost of `LAYERS` that is a segment of ``op_name``, or
     None."""
     found = None
-    for seg in op_name.split("/"):
-        m = WRAPPED.fullmatch(seg)
-        core = m.group(1) if m else seg
+    for core in segments(op_name):
         if core in LAYERS:
             found = core
     return found
@@ -142,15 +154,19 @@ def op_names(hlo_text: str) -> dict:
     return out
 
 
-def split(plain: dict, names: dict) -> dict:
+def split(plain: dict, names: dict, within=None) -> dict:
     """Per chip of a plain trace, self ns under each ``layer/pass``;
-    ``names`` as `op_names` gives them for the step."""
+    ``names`` as `op_names` gives them for the step.  With ``within``,
+    of the ops whose name stack holds that segment alone."""
     out = {}
     for chip, dev in plain["devices"].items():
         keys: dict = {}
         for op, ns in dev["self"].items():
-            instr = op.split(" ", 1)[0]
-            key = layer_key(names.get(instr))
+            op_name = names.get(op.split(" ", 1)[0])
+            if within is not None and (
+                    op_name is None or within not in segments(op_name)):
+                continue
+            key = layer_key(op_name)
             keys[key] = keys.get(key, 0) + ns
         out[chip] = keys
     return out
@@ -200,25 +216,36 @@ def step_op_names(job) -> dict:
     return op_names(lowered.compile().as_text())
 
 
-def of_run(rec):
-    """The traced training run's `split`, computed once; None for any
-    other run."""
+def of_run(rec, within=None):
+    """The traced training run's `split` (``within`` a segment, where
+    given), the whole one computed once; None for any other run."""
     if rec["kind"] != "train" or not rec["window"].traced_steps \
             or "timeline" not in rec:
         return None
+    if within is not None:
+        return split(rec["timeline"].plain, _names(rec), within)
     if "layer_split" not in rec:
-        rec["layer_split"] = split(rec["timeline"].plain,
-                                   step_op_names(rec["job"]))
+        rec["layer_split"] = split(rec["timeline"].plain, _names(rec))
     return rec["layer_split"]
 
 
-def step_ms(rec, layer=None, pass_=None):
-    """Self ms per traced step under ``layer``/``pass_``, mean over the
-    chips; None where the step names no model layer or nothing ran
-    there."""
+def _names(rec) -> dict:
+    """`step_op_names` of the run's step, computed once."""
+    if "op_names" not in rec:
+        rec["op_names"] = step_op_names(rec["job"])
+    return rec["op_names"]
+
+
+def step_ms(rec, layer=None, pass_=None, within=None):
+    """Self ms per traced step under ``layer``/``pass_`` (of the ops
+    whose name stack holds the segment ``within``, where given), mean
+    over the chips; None where the step names no model layer or nothing
+    ran there."""
     keys = of_run(rec)
     if keys is None or not names_layers(keys):
         return None
+    if within is not None:
+        keys = of_run(rec, within)
     ns = sum(layer_ns(k, layer, pass_) for k in keys.values()) / len(keys)
     if ns <= 0:
         return None

@@ -66,11 +66,13 @@ def build(conf: dict, traffic: dict, devices, key):
     from repro.launch import serve as S
     from repro.models.transformer import Model
     cfg = bm.program_config(conf)
+    family = harness.family(conf)
     # the model's settings as the serving launcher sets them
     model = Model(cfg, **harness.launcher_model_kwargs(S, S.main, []))
     template = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     with jax.default_device(devices[0]):
-        params = jax.jit(lambda k: bm.make_weights(template, k))(key)
+        params = jax.jit(lambda k: bm.make_weights(family, template,
+                                                   k))(key)
     sch = make_scheduler(model, params, traffic)
     return cfg, model, template, params, sch
 
@@ -237,17 +239,20 @@ def _gap_fn(conf: dict, lowp: bool):
     the f32 reference's best logit minus its logit of the served token
     at each position.  With ``lowp`` the served token is the one the
     fp8 reference puts first (the control)."""
-    rc = bm.reference_config(conf)
+    family = harness.family(conf)
+    rc = family.reference_config(conf)
     V = conf["vocab_size"]
 
     def fn(params, tokens, pos, served):
-        x = R.hidden(params, tokens, rc, R.matmul(False), remat=False)
+        x = family.hidden(params, tokens, rc, R.matmul(False), remat=False)
         xs = x[pos]
-        ref = R.matmul(False)("sd,dv->sv", xs, params["unembed"])[:, :V]
+        ref = R.matmul(False)("sd,dv->sv", xs,
+                              family.unembed(params))[:, :V]
         if lowp:
-            x8 = R.hidden(params, tokens, rc, R.matmul(True), remat=False)
+            x8 = family.hidden(params, tokens, rc, R.matmul(True),
+                               remat=False)
             low = R.matmul(True)("sd,dv->sv", x8[pos],
-                                 params["unembed"])[:, :V]
+                                 family.unembed(params))[:, :V]
             served = jnp.argmax(low, axis=-1)
         got = jnp.take_along_axis(ref, served[:, None], axis=-1)[:, 0]
         return jnp.max(ref, axis=-1) - got
@@ -327,9 +332,10 @@ def calibrate(conf, traffic, devices, seeds, seconds) -> list:
     cfg, model, template, params, sch = build(conf, traffic, devices, key0)
     warm_up(sch, cfg.vocab_size)
     del sch
+    family = harness.family(conf)
     out = []
     for seed in seeds:
-        params = jax.jit(lambda k: bm.make_weights(template, k))(
+        params = jax.jit(lambda k: bm.make_weights(family, template, k))(
             harness.seed_key(seed))
         sch = make_scheduler(model, params, traffic)
         warm_up(sch, cfg.vocab_size)

@@ -16,7 +16,7 @@ def test_qwen3_by_hand():
     # the 128 padded vocabulary columns do not count)
     layer = 1024 * 2048 + 2 * 1024 * 1024 + 2048 * 1024 + 3 * 1024 * 3072
     n = 28 * layer + 1024 * 151936
-    assert flops.matmul_params(conf) == n == 595_984_384
+    assert harness.family(conf).matmul_params(conf) == n == 595_984_384
     # 2 sequences of 2048: 6 N T plus causal attention, 3 x 4 H hd pairs
     attn = 3 * 28 * 4 * 16 * 128 * (2048 * 2049 // 2) * 2
     assert flops.train_step_flops(conf, 1, 2, 2048) == \
@@ -29,7 +29,7 @@ def test_stablelm_by_hand():
     conf = _conf("stablelm-3b-6l")
     layer = 4 * 2560 * 2560 + 3 * 2560 * 6912
     n = 6 * layer + 2560 * 50304
-    assert flops.matmul_params(conf) == n == 604_569_600
+    assert harness.family(conf).matmul_params(conf) == n == 604_569_600
     attn = 3 * 6 * 4 * 32 * 80 * (2048 * 2049 // 2)
     assert flops.train_step_flops(conf, 4, 1, 2048) == \
         4 * (6.0 * n * 2048 + attn)

@@ -179,3 +179,38 @@ def test_readers_on_a_step_compiled_here(tiny):
     loose = sum(k.split("/")[0] in ("other", "unscoped") for k in by_key)
     assert values["unscoped_share.train"] == pytest.approx(
         100 * (loose + 1) / len(ops))
+
+
+# Self ns under `LAYERS` of the recorded qwen3 trace (three steps, one
+# chip), as the split read it before a reader could ask for a segment.
+LAYERS_NS = 4_056_452_413
+
+
+def test_a_segment_is_read_within_the_layers():
+    """``within`` reads the ops whose name stack holds a segment, and
+    asking for one leaves the split by layer as it was."""
+    plain = timeline.load(DATA / "timeline-qwen3-0.6b.train.w1.scoped.json.gz")
+    rec = {"kind": "train", "timeline": timeline.Timeline(plain),
+           "window": train.Window(traced_steps=3),
+           "op_names": plain["op_names"]}
+
+    def by_layer(within=None):
+        return {lay: scopes.step_ms(rec, lay, within=within) or 0.0
+                for lay in scopes.LAYERS}
+
+    whole = by_layer()
+    assert sum(whole.values()) == pytest.approx(LAYERS_NS * 1e-6 / 3,
+                                                rel=1e-12)
+    assert by_layer("mlp") == {**dict.fromkeys(scopes.LAYERS, 0.0),
+                               "mlp": whole["mlp"]}
+    # the layer scan holds attention, the MLP and its own ops, and
+    # nothing of the embedding, the head or the tail
+    scan = by_layer("layers")
+    assert scan["mlp"] == whole["mlp"] and scan["layers"] == whole["layers"]
+    assert 0.99 * whole["attn"] < scan["attn"] <= whole["attn"]
+    assert scan["embed"] == scan["head"] == scan["tail"] == 0.0
+    # a segment that no op carries reads 0, and its reader nothing
+    none = scopes.split(plain, plain["op_names"], within="moe_dispatch")
+    assert all(scopes.layer_ns(k) == 0 for k in none.values())
+    assert scopes.step_ms(rec, "mlp", within="moe_dispatch") is None
+    assert by_layer() == whole

@@ -89,4 +89,4 @@ def test_models_are_built_as_the_launchers_build_them():
     assert S.Model is Model and kw["remat"] is False
     args = train.trainer_args(harness.load_traffic("train-w1-2x2048"))
     kw = harness.launcher_model_kwargs(T, T.run, args)
-    assert T.Model is Model and kw["remat"] is True and "q_chunk" in kw
+    assert T.Model is Model and kw["remat"] is True and "loss_chunk" in kw

@@ -7,7 +7,8 @@ of the momentum after step one (the first gradient as the optimizer got
 it) and the per-leaf norm of the weights' change after the checked
 steps.  The window then runs the same object for ``--seconds``; the
 reference (`reference.py`) follows the checked steps after the window,
-once the program's state is freed.
+once the program's state is freed, over the loss of the
+configuration's family (`families/`).
 """
 from __future__ import annotations
 
@@ -29,6 +30,7 @@ import reference as R
 @dataclass
 class Job:
     conf: dict
+    family: object
     traffic: dict
     cfg: object
     model: object
@@ -53,8 +55,9 @@ class Readings:
 
 
 # steps dispatched ahead of the oldest unfinished one: enough queued work
-# that a host pause of a few steps leaves the chip busy
-IN_FLIGHT = 4
+# that a host pause leaves the chip busy (pauses of 1.25-1.9 s were seen
+# on a v5e host; 12 steps hold 2.4 s of work at a 0.2-s step)
+IN_FLIGHT = 12
 
 
 @dataclass
@@ -86,6 +89,7 @@ def build(conf: dict, traffic: dict, devices) -> Job:
     from repro.launch.mesh import mesh_for_spec
     from repro.models.transformer import Model
 
+    family = harness.family(conf)
     cfg = bm.program_config(conf)
     args = trainer_args(traffic)
     W, B, S = args.workers, args.batch_per_worker, args.seq
@@ -95,7 +99,7 @@ def build(conf: dict, traffic: dict, devices) -> Job:
         """The program's model; only its init is the benchmark's."""
 
         def init(self, key):
-            return bm.make_weights(template, key)
+            return bm.make_weights(family, template, key)
 
     model = BenchModel(cfg, **model_kwargs)
     template = jax.eval_shape(lambda k: Model.init(model, k),
@@ -131,8 +135,8 @@ def build(conf: dict, traffic: dict, devices) -> Job:
     mesh = None if len(devices) == 1 else mesh_for_spec(
         ClusterSpec.uniform(W), devices=devices)
     engine = Engine(model, alg, mesh=mesh)
-    return Job(conf, traffic, cfg, model, alg, engine, list(devices),
-               template, W, B, S, hp)
+    return Job(conf, family, traffic, cfg, model, alg, engine,
+               list(devices), template, W, B, S, hp)
 
 
 def make_batch(seed: int, step: int, job: Job, half: bool = False) -> dict:
@@ -172,7 +176,7 @@ def _norms_fn():
 
 def _change_fn(job: Job):
     def change(params, key):
-        w0 = bm.make_weights(job.template, key)
+        w0 = bm.make_weights(job.family, job.template, key)
         return jnp.stack(R.leaf_norms(jax.tree.map(
             lambda p, w: p.astype(jnp.float32) - w[None].astype(jnp.float32),
             params, w0)))
@@ -251,7 +255,7 @@ def reference_readings(job: Job, seed: int, key, *, lowp: bool = False,
                        exchange: bool = True) -> Readings:
     """The reference's readings of the checked steps, on the same chips,
     one worker per chip (W > 1) or one chip."""
-    rc = bm.reference_config(job.conf)
+    rc = job.family.reference_config(job.conf)
     sh = None
     if job.W > 1 and len(job.devices) > 1:
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -259,14 +263,15 @@ def reference_readings(job: Job, seed: int, key, *, lowp: bool = False,
         sh = NamedSharding(mesh, P("w"))
 
     def init(k):
-        w = bm.make_weights(job.template, k)
+        w = bm.make_weights(job.family, job.template, k)
         w = jax.tree.map(lambda x: jnp.broadcast_to(
             x.astype(jnp.float32)[None], (job.W,) + x.shape), w)
         return w, jax.tree.map(jnp.zeros_like, w)
 
     w, m = jax.jit(init, out_shardings=sh)(key)
     step = jax.jit(lambda w, m, lp, b, t: R.dc_step(
-        w, m, lp, b, t, hp=job.hp, rc=rc, lowp=lowp, exchange=exchange),
+        w, m, lp, b, t, hp=job.hp, rc=rc, seq_loss=job.family.seq_loss,
+        lowp=lowp, exchange=exchange),
         donate_argnums=(0, 1), out_shardings=(sh, sh, None, None))
     lr_prev = jnp.float32(0.0)
     losses, grads = [], None
